@@ -13,13 +13,20 @@ stalling decode behind admission (Sarathi-style chunked prefill).
 Grid (B, KV, nb) exactly as in ``paged_decode``: the block table rides in as
 a scalar-prefetch operand so the k/v BlockSpec index maps DMA pool block
 ``table[b, j]`` directly, and the G grouped query heads of a KV head are
-processed together.  The flash running softmax in VMEM scratch simply gains
-a leading chunk axis ([C, G] stats, [C, G, hd] accumulator).  Validity is
-purely positional per query: row ``r`` of table entry ``j`` holds sequence
-position ``j*block + r``, so ``pos <= q_pos[c]`` covers causality within the
-chunk, the boundary block's tail, AND 0-padded table entries (dump-block
-positions exceed every valid query); padding queries (``q_pos`` = -2^30)
-mask every key and emit zeros.
+processed together, so each pool block is read once per head group.
+
+TPU tiling: the pool is viewed lane-merged as ``[N_rows, KV*hd]`` and the
+queries as ``[B, C, H*hd]`` (both free reshapes).  A KV head's G query heads
+are adjacent in the head axis, so one query block is ``(C, G*hd)`` at
+``(b, 0, h)`` and the kernel walks the G heads as static ``hd``-wide lane
+slices; the output is written back in the same layout, so no transpose
+surrounds the launch.  Positions come in as a ``[B, C, 1]`` column.
+
+Validity is purely positional per query: row ``r`` of table entry ``j``
+holds sequence position ``j*block + r``, so ``pos <= q_pos[c]`` covers
+causality within the chunk, the boundary block's tail, AND 0-padded table
+entries (dump-block positions exceed every valid query); padding queries
+(``q_pos`` = -2^30) mask every key and emit zeros.
 """
 from __future__ import annotations
 
@@ -31,19 +38,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_prefill import _scratch
-
 NEG_INF = -1e30
 
 
 def supported(q, k_pool, v_pool, block: int) -> bool:
+    """Shapes the compiled TPU kernel accepts: chunks no longer than a pool
+    block, a lane-aligned head dim and a sublane-aligned pool block."""
     B, C, H, hd = q.shape
     KV = k_pool.shape[1]
     return (
-        C >= 1
-        and C <= block
+        1 <= C <= block
         and H % KV == 0
-        and hd <= 256
+        and hd % 128 == 0
+        and block % 8 == 0
         and k_pool.shape[0] % block == 0
         and q.dtype in (jnp.float32, jnp.bfloat16)
     )
@@ -54,7 +61,8 @@ def _kernel(
     q_ref, k_ref, v_ref, qp_ref,  # inputs
     o_ref,  # output
     m_ref, l_ref, acc_ref,  # scratch
-    *, nb: int, block: int, chunk: int, window: Optional[int], scale: float,
+    *, nb: int, block: int, groups: int, hd: int, window: Optional[int],
+    scale: float,
 ):
     ib = pl.program_id(2)
 
@@ -64,38 +72,40 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    C, G, hd = chunk, q_ref.shape[3], q_ref.shape[4]
-    qg = q_ref[0, 0].astype(jnp.float32).reshape(C * G, hd)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # [block, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    qp = qp_ref[0].astype(jnp.int32)  # [C]
-
-    s = jax.lax.dot_general(
-        qg, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    ).reshape(C, G, block) * scale
+    k = k_ref[...].astype(jnp.float32)  # [block, hd]
+    v = v_ref[...].astype(jnp.float32)
+    qp = qp_ref[0]  # [C, 1]
 
     # sequence position of each row of this table entry (by construction)
-    kp = ib * block + jax.lax.broadcasted_iota(jnp.int32, (C, block), 1)
-    mask = kp <= qp[:, None]  # [C, block]
+    kp = ib * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    mask = kp <= qp  # [C, block]
     if window is not None:
-        mask &= kp > qp[:, None] - window
-    s = jnp.where(mask[:, None, :], s, NEG_INF)
+        mask &= kp > qp - window
 
-    m_prev = m_ref[...]  # [C, G]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask[:, None, :], jnp.exp(s - m_new[..., None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[..., None] + jax.lax.dot_general(
-        p.reshape(C * G, block), v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ).reshape(C, G, hd)
-    m_ref[...] = m_new
+    for g in range(groups):  # the KV head's query heads, hd-wide lane slices
+        lanes = slice(g * hd, (g + 1) * hd)
+        qg = q_ref[0, :, lanes].astype(jnp.float32)  # [C, hd]
+        s = jax.lax.dot_general(
+            qg, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * scale  # [C, block]
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_ref[g]  # [C, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        l_ref[g] = l_ref[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[:, lanes] = acc_ref[:, lanes] * alpha + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        m_ref[g] = m_new
 
     @pl.when(ib == nb - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)[..., None]
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        for g in range(groups):
+            lanes = slice(g * hd, (g + 1) * hd)
+            l = jnp.maximum(l_ref[g], 1e-30)
+            o_ref[0, :, lanes] = (acc_ref[:, lanes] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -117,37 +127,38 @@ def chunked_prefill_attention(
     G = H // KV
     nb = block_table.shape[1]
 
-    kb = k_pool.reshape(-1, block, KV, hd)  # [n_blocks, block, KV, hd]
-    vb = v_pool.reshape(-1, block, KV, hd)
-    # [B, C, H, hd] -> [B, KV, C, G, hd]: one grid step covers a KV head
-    # group across the whole chunk.
-    qg = q.reshape(B, C, KV, G, hd).transpose(0, 2, 1, 3, 4)
+    kf = k_pool.reshape(-1, KV * hd)  # lane-merged views, no copy
+    vf = v_pool.reshape(-1, KV * hd)
+    qf = q.reshape(B, C, H * hd)
     tbl = block_table.astype(jnp.int32)
+    qp = q_pos.astype(jnp.int32).reshape(B, C, 1)
 
     kernel = functools.partial(
-        _kernel, nb=nb, block=block, chunk=C, window=window,
+        _kernel, nb=nb, block=block, groups=G, hd=hd, window=window,
         scale=1.0 / (hd**0.5),
     )
+    q_spec = pl.BlockSpec((1, C, G * hd), lambda b, h, ib, t: (b, 0, h))
+    kv_spec = pl.BlockSpec((block, hd), lambda b, h, ib, t: (t[b, ib], h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, KV, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, C, G, hd), lambda b, h, ib, t: (b, h, 0, 0, 0)),
-            pl.BlockSpec((1, block, 1, hd), lambda b, h, ib, t: (t[b, ib], 0, h, 0)),
-            pl.BlockSpec((1, block, 1, hd), lambda b, h, ib, t: (t[b, ib], 0, h, 0)),
-            pl.BlockSpec((1, C), lambda b, h, ib, t: (b, 0)),
+            q_spec,
+            kv_spec,
+            kv_spec,
+            pl.BlockSpec((1, C, 1), lambda b, h, ib, t: (b, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, C, G, hd), lambda b, h, ib, t: (b, h, 0, 0, 0)),
+        out_specs=q_spec,
         scratch_shapes=[
-            _scratch((C, G), jnp.float32),
-            _scratch((C, G), jnp.float32),
-            _scratch((C, G, hd), jnp.float32),
+            pltpu.VMEM((G, C, 1), jnp.float32),
+            pltpu.VMEM((G, C, 1), jnp.float32),
+            pltpu.VMEM((C, G * hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, KV, C, G, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, C, H * hd), q.dtype),
         interpret=interpret,
-    )(tbl, qg, kb, vb, q_pos)
-    return out.transpose(0, 2, 1, 3, 4).reshape(B, C, H, hd)
+    )(tbl, qf, kf, vf, qp)
+    return out.reshape(B, C, H, hd)

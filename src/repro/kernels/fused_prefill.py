@@ -17,10 +17,14 @@ p == 0), so skipping its arithmetic changes nothing.  With a small recompute
 fraction most (q, kv) tiles are in the strictly-causal region anyway — the
 compute saving of selective recompute comes from the short q side.
 
-Grid/BlockSpec layout is inherited unchanged from ``flash_prefill``:
-  grid = (B, H, nQ, nKV), kv innermost; running (m, l, acc) in VMEM scratch.
-Exactness contract: ``ref.fused_prefill_ref`` bitwise at r=1.0 against plain
-full prefill (tests/test_fusion.py).
+Grid/BlockSpec layout is inherited unchanged from ``flash_prefill``
+(``head_specs``): grid = (B, H, nQ, nKV), kv innermost, lane-merged q/k/v
+views; running (m, l, acc) in VMEM scratch.
+Exactness contract (tests/test_fusion.py): ``ref.fused_prefill_ref`` at full
+query coverage equals plain causal attention bitwise; ``lm.prefill_fused`` at
+r=1.0 equals a plain full ``lm.prefill`` bitwise, except on qwen2 (q/k/v
+biases), where XLA sums the two in a different order and they agree to f32
+reordering tolerance.
 """
 from __future__ import annotations
 
@@ -31,15 +35,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.flash_prefill import _scratch
+# ``supported``: the same tiling as flash_prefill, so the same shapes
+from repro.kernels.flash_prefill import head_scratch, head_specs, supported  # noqa: F401
 
 NEG_INF = -1e30
-
-
-def supported(q, k, v, window: Optional[int] = None) -> bool:
-    B, Sq, H, hd = q.shape
-    KV = k.shape[2]
-    return H % KV == 0 and hd <= 256 and q.dtype in (jnp.float32, jnp.bfloat16)
 
 
 def _kernel(
@@ -56,8 +55,8 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qp = qp_ref[0, :].astype(jnp.int32)  # [bq]
-    kp = kp_ref[0, :].astype(jnp.int32)  # [bkv]
+    qp = qp_ref[0]  # [bq, 1]
+    kp = kp_ref[0]  # [1, bkv]
 
     # Early-out: every kv position in this block is invalid or beyond the
     # q block's causal reach -> the whole tile is masked, an exact no-op.
@@ -66,36 +65,36 @@ def _kernel(
 
     @pl.when(kp_min <= q_max)
     def _update():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)  # [bq, hd]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # [bkv, hd]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32)  # [bq, hd]
+        k = k_ref[0].astype(jnp.float32)  # [bkv, hd]
+        v = v_ref[0].astype(jnp.float32)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * scale  # [bq, bkv]
 
-        mask = (kp >= 0)[None, :]
-        mask &= kp[None, :] <= qp[:, None]
+        mask = kp >= 0
+        mask &= kp <= qp
         if window is not None:
-            mask &= kp[None, :] > qp[:, None] - window
+            mask &= kp > qp - window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        m_prev = m_ref[...]  # [bq, 1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
+        p = jnp.exp(s - m_new)
         p = jnp.where(mask, p, 0.0)
 
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
         )
         m_ref[...] = m_new
 
     @pl.when(ik == n_kv - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -132,27 +131,23 @@ def fused_flash_attention(
     Sq_p, Skv_p = Sq + pad_q, Skv + pad_kv
     n_q, n_kv = Sq_p // bq, Skv_p // bkv
 
-    grid = (B, H, n_q, n_kv)
+    q_spec, kv_spec, q_col, kv_row = head_specs(bq, bkv, hd, G)
     kernel = functools.partial(
         _kernel, window=window, n_kv=n_kv, scale=1.0 / (hd**0.5)
     )
     out = pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, bq, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bkv, 1, hd), lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bkv, 1, hd), lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bkv), lambda b, h, iq, ik: (b, ik)),
-        ],
-        out_specs=pl.BlockSpec((1, bq, 1, hd), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H, hd), q.dtype),
-        scratch_shapes=[
-            _scratch((bq,), jnp.float32),
-            _scratch((bq,), jnp.float32),
-            _scratch((bq, hd), jnp.float32),
-        ],
+        grid=(B, H, n_q, n_kv),
+        in_specs=[q_spec, kv_spec, kv_spec, q_col, kv_row],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Sq_p, H * hd), q.dtype),
+        scratch_shapes=head_scratch(bq, hd),
         interpret=interpret,
-    )(q, k, v, q_pos, kv_pos)
-    return out[:, :Sq]
+    )(
+        q.reshape(B, Sq_p, H * hd),
+        k.reshape(B, Skv_p, KV * hd),
+        v.reshape(B, Skv_p, KV * hd),
+        q_pos.astype(jnp.int32).reshape(B, Sq_p, 1),
+        kv_pos.astype(jnp.int32).reshape(B, 1, Skv_p),
+    )
+    return out.reshape(B, Sq_p, H, hd)[:, :Sq]

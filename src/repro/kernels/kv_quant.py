@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.flash_prefill import _scratch  # noqa: F401 (shared helper)
-
 
 def supported(x) -> bool:
     return x.ndim >= 2 and x.shape[-1] >= 8

@@ -7,13 +7,22 @@ Dispatch policy (``REPRO_KERNEL_MODE`` env var or :func:`set_kernel_mode`):
                               validation; used by the kernel test suite).
   * ``pallas``              — Pallas compiled (TPU).
 
+When the mode resolves to Pallas, an entry point runs its kernel or raises
+:class:`KernelUnsupported` naming the op and shapes — it never quietly
+substitutes the jnp reference.  The only jnp paths under Pallas are explicit
+rules: a prefill with fewer than :data:`MIN_KERNEL_Q` query tokens, and
+attention that carries a ``kv_valid`` bitmap.  Every dispatch decision is
+counted per op (:func:`dispatch_counts`) so a run can show which path its
+attention took.
+
 The chunked SSD implementation lives here (it is jnp-level and runs on every
 backend); its exactness oracle is ``ref.ssd_scan_ref``.
 """
 from __future__ import annotations
 
+import collections
 import os
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +30,19 @@ import jax.numpy as jnp
 from repro.kernels import ref
 
 _MODE = None
+
+# (op, "kernel" | "jnp") -> dispatch decisions.  Entry points run while jit
+# traces, so this counts traced calls: one per call site per compilation,
+# not one per execution of the compiled program.
+_COUNTS: collections.Counter = collections.Counter()
+
+# Prefill launches shorter than one 128-row query block run the jnp
+# reference: such a launch is a single partial tile.
+MIN_KERNEL_Q = 128
+
+
+class KernelUnsupported(ValueError):
+    """A Pallas dispatch met shapes its kernel cannot tile."""
 
 
 def set_kernel_mode(mode: Optional[str]) -> None:
@@ -44,8 +66,37 @@ def _use_pallas() -> Tuple[bool, bool]:
         return True, False
     if mode == "pallas_interpret":
         return True, True
-    # auto
+    if mode != "auto":
+        raise ValueError(f"unknown kernel mode {mode!r}")
     return jax.default_backend() == "tpu", False
+
+
+def dispatch_counts() -> Dict[str, Dict[str, int]]:
+    """``{op: {"kernel": n, "jnp": m}}`` traced dispatch decisions so far."""
+    out: Dict[str, Dict[str, int]] = {}
+    for (op, path), n in sorted(_COUNTS.items()):
+        out.setdefault(op, {"kernel": 0, "jnp": 0})[path] = n
+    return out
+
+
+def reset_dispatch_counts() -> None:
+    _COUNTS.clear()
+
+
+def _jnp(op: str) -> None:
+    _COUNTS[(op, "jnp")] += 1
+
+
+def _kernel(op: str, ok: bool, *arrays: jax.Array) -> None:
+    """Count a kernel dispatch, or raise if the kernel cannot take it."""
+    if not ok:
+        shapes = ", ".join(f"{a.dtype}{list(a.shape)}" for a in arrays)
+        raise KernelUnsupported(
+            f"{op}: the Pallas kernel does not support operands {shapes} "
+            f"(kernel mode {kernel_mode()!r}); run with REPRO_KERNEL_MODE=ref "
+            "to use the jnp reference"
+        )
+    _COUNTS[(op, "kernel")] += 1
 
 
 # --------------------------------------------------------------------------- #
@@ -69,14 +120,15 @@ def flash_attention(
 ) -> jax.Array:
     """Generalised GQA attention — see ``ref.attention_ref`` for semantics."""
     use_pallas, interpret = _use_pallas()
-    if use_pallas and kv_valid is None and q.shape[1] >= 128:
+    if use_pallas and kv_valid is None and q.shape[1] >= MIN_KERNEL_Q:
         from repro.kernels import flash_prefill
 
-        if flash_prefill.supported(q, k, v, window=window):
-            return flash_prefill.flash_attention(
-                q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=causal, window=window,
-                interpret=interpret,
-            )
+        _kernel("flash_prefill", flash_prefill.supported(q, k, v, window=window), q, k, v)
+        return flash_prefill.flash_attention(
+            q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=causal, window=window,
+            interpret=interpret,
+        )
+    _jnp("flash_prefill")
     if kv_shard_enabled() and kv_valid is None:
         out = _kv_sharded_attention(
             q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=causal, window=window
@@ -109,14 +161,15 @@ def packed_attention(
     suffix-prefill kernel of batched admission.  See
     ``ref.packed_attention_ref`` for semantics."""
     use_pallas, interpret = _use_pallas()
-    if use_pallas and q.shape[1] >= 128:
+    if use_pallas and q.shape[1] >= MIN_KERNEL_Q:
         from repro.kernels import packed_prefill
 
-        if packed_prefill.supported(q, k, v, window=window):
-            return packed_prefill.packed_flash_attention(
-                q, k, v, q_pos=q_pos, kv_pos=kv_pos, q_seg=q_seg, kv_seg=kv_seg,
-                causal=causal, window=window, interpret=interpret,
-            )
+        _kernel("packed_prefill", packed_prefill.supported(q, k, v, window=window), q, k, v)
+        return packed_prefill.packed_flash_attention(
+            q, k, v, q_pos=q_pos, kv_pos=kv_pos, q_seg=q_seg, kv_seg=kv_seg,
+            causal=causal, window=window, interpret=interpret,
+        )
+    _jnp("packed_prefill")
     return ref.packed_attention_ref(
         q, k, v, q_pos=q_pos, kv_pos=kv_pos, q_seg=q_seg, kv_seg=kv_seg,
         causal=causal, window=window,
@@ -137,14 +190,15 @@ def fused_prefill(
     ``ref.fused_prefill_ref`` for semantics and the r=1.0 bit-exactness
     contract vs plain full prefill."""
     use_pallas, interpret = _use_pallas()
-    if use_pallas and q.shape[1] >= 128:
+    if use_pallas and q.shape[1] >= MIN_KERNEL_Q:
         from repro.kernels import fused_prefill as fpk
 
-        if fpk.supported(q, k, v, window=window):
-            return fpk.fused_flash_attention(
-                q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window,
-                interpret=interpret,
-            )
+        _kernel("fused_prefill", fpk.supported(q, k, v, window=window), q, k, v)
+        return fpk.fused_flash_attention(
+            q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window,
+            interpret=interpret,
+        )
+    _jnp("fused_prefill")
     return ref.fused_prefill_ref(
         q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window
     )
@@ -164,11 +218,12 @@ def decode_attention(
     if use_pallas:
         from repro.kernels import decode_attention as dk
 
-        if dk.supported(q, k, v):
-            return dk.decode_attention(
-                q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window, kv_valid=kv_valid,
-                interpret=interpret,
-            )
+        _kernel("decode_attention", dk.supported(q, k, v), q, k, v)
+        return dk.decode_attention(
+            q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window, kv_valid=kv_valid,
+            interpret=interpret,
+        )
+    _jnp("decode_attention")
     if kv_shard_enabled() and kv_valid is None:
         out = _kv_sharded_attention(
             q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=True, window=window
@@ -197,11 +252,12 @@ def paged_decode(
     if use_pallas:
         from repro.kernels import paged_decode as pdk
 
-        if pdk.supported(q, k_pool, v_pool, block):
-            return pdk.paged_decode_attention(
-                q, k_pool, v_pool, block_table=block_table, q_pos=q_pos,
-                block=block, window=window, interpret=interpret,
-            )
+        _kernel("paged_decode", pdk.supported(q, k_pool, v_pool, block), q, k_pool, v_pool)
+        return pdk.paged_decode_attention(
+            q, k_pool, v_pool, block_table=block_table, q_pos=q_pos,
+            block=block, window=window, interpret=interpret,
+        )
+    _jnp("paged_decode")
     return ref.paged_decode_ref(
         q, k_pool, v_pool, block_table=block_table, q_pos=q_pos, block=block,
         window=window,
@@ -226,11 +282,12 @@ def chunked_prefill(
     if use_pallas:
         from repro.kernels import chunked_prefill as cpk
 
-        if cpk.supported(q, k_pool, v_pool, block):
-            return cpk.chunked_prefill_attention(
-                q, k_pool, v_pool, block_table=block_table, q_pos=q_pos,
-                block=block, window=window, interpret=interpret,
-            )
+        _kernel("chunked_prefill", cpk.supported(q, k_pool, v_pool, block), q, k_pool, v_pool)
+        return cpk.chunked_prefill_attention(
+            q, k_pool, v_pool, block_table=block_table, q_pos=q_pos,
+            block=block, window=window, interpret=interpret,
+        )
+    _jnp("chunked_prefill")
     return ref.chunked_prefill_ref(
         q, k_pool, v_pool, block_table=block_table, q_pos=q_pos, block=block,
         window=window,
@@ -252,11 +309,8 @@ def kv_shard_enabled() -> bool:
 
 
 def _mesh_axes_for_kv_shard(batch: int, skv: int):
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:  # pragma: no cover
-        return None
-    if mesh is None or mesh.empty or "model" not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return None
     m = mesh.shape["model"]
     if m <= 1 or skv % m != 0:
@@ -379,11 +433,12 @@ def ssd_chunked(
     if use_pallas:
         from repro.kernels import ssd_scan
 
-        if ssd_scan.supported(x, dt, A, B_, C, chunk=chunk):
-            return ssd_scan.ssd_chunked(
-                x, dt, A, B_, C, chunk=chunk, initial_state=initial_state,
-                interpret=interpret,
-            )
+        _kernel("ssd_scan", ssd_scan.supported(x, dt, A, B_, C, chunk=chunk), x, B_)
+        return ssd_scan.ssd_chunked(
+            x, dt, A, B_, C, chunk=chunk, initial_state=initial_state,
+            interpret=interpret,
+        )
+    _jnp("ssd_scan")
     return ssd_chunked_jnp(x, dt, A, B_, C, chunk=chunk, initial_state=initial_state)
 
 
@@ -483,8 +538,9 @@ def kv_quant(x: jax.Array) -> Tuple[jax.Array, jax.Array]:
     if use_pallas:
         from repro.kernels import kv_quant as kq
 
-        if kq.supported(x):
-            return kq.kv_quant(x, interpret=interpret)
+        _kernel("kv_quant", kq.supported(x), x)
+        return kq.kv_quant(x, interpret=interpret)
+    _jnp("kv_quant")
     return ref.kv_quant_ref(x)
 
 
@@ -493,6 +549,7 @@ def kv_dequant(q: jax.Array, scale: jax.Array, dtype=jnp.bfloat16) -> jax.Array:
     if use_pallas:
         from repro.kernels import kv_quant as kq
 
-        if kq.supported(q):
-            return kq.kv_dequant(q, scale, dtype=dtype, interpret=interpret)
+        _kernel("kv_dequant", kq.supported(q), q)
+        return kq.kv_dequant(q, scale, dtype=dtype, interpret=interpret)
+    _jnp("kv_dequant")
     return ref.kv_dequant_ref(q, scale, dtype=dtype)

@@ -3,13 +3,20 @@ KV block pool.
 
 Decode under the paged layout reads, per sequence, exactly the live
 ``block``-token blocks its block table names — nothing else leaves HBM.  The
-pool is ONE array shared by every batch slot ([n_blocks, block, KV, hd]);
+pool is ONE array shared by every batch slot ([N_rows, KV, hd], rows
+``j*block .. (j+1)*block`` forming pool block ``j``);
 ``block_table[b, j]`` is the pool block holding sequence ``b``'s tokens
 ``[j*block, (j+1)*block)``.  The table rides in as a scalar-prefetch operand
 (``pltpu.PrefetchScalarGridSpec``) so the k/v BlockSpec index maps can
 dereference it — the DMA for grid step (b, h, j) fetches pool block
 ``table[b, j]`` directly; no gathered copy of the cache is ever
 materialised.
+
+TPU tiling: the pool is viewed lane-merged as ``[N_rows, KV*hd]`` (a free
+reshape of the contiguous array), so one grid step's k/v block is
+``(block, hd)`` at block index ``(table[b, j], h)`` — both minor dims are
+(8, 128)-aligned when ``hd % 128 == 0``.  The query positions ride in SMEM
+as a second scalar-prefetch operand.
 
 Grid (B, KV, nb) with the G grouped query heads of a KV head processed
 together (the cache block is read once per head group), flash-style running
@@ -31,26 +38,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_prefill import _scratch
-
 NEG_INF = -1e30
 
 
 def supported(q, k_pool, v_pool, block: int) -> bool:
+    """Shapes the compiled TPU kernel accepts: one query per sequence, a
+    lane-aligned head dim (the ``[N_rows, KV*hd]`` view takes ``hd``-wide
+    blocks) and a sublane-aligned pool block."""
     B, Sq, H, hd = q.shape
     KV = k_pool.shape[1]
     return (
         Sq == 1
         and H % KV == 0
-        and hd <= 256
+        and hd % 128 == 0
+        and block % 8 == 0
         and k_pool.shape[0] % block == 0
         and q.dtype in (jnp.float32, jnp.bfloat16)
     )
 
 
 def _kernel(
-    tbl_ref,  # scalar-prefetch: [B, nb] int32
-    q_ref, k_ref, v_ref, qp_ref,  # inputs
+    tbl_ref, qpos_ref,  # scalar-prefetch: [B, nb], [B] int32
+    q_ref, k_ref, v_ref,  # inputs
     o_ref,  # output
     m_ref, l_ref, acc_ref,  # scratch
     *, nb: int, block: int, window: Optional[int], scale: float,
@@ -63,36 +72,36 @@ def _kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    qg = q_ref[0, 0, :, :].astype(jnp.float32)  # [G, hd]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)  # [block, hd]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    qp = qp_ref[0, 0].astype(jnp.int32)  # scalar
+    qg = q_ref[0, 0].astype(jnp.float32)  # [G, hd]
+    k = k_ref[...].astype(jnp.float32)  # [block, hd]
+    v = v_ref[...].astype(jnp.float32)
+    qp = qpos_ref[pl.program_id(0)]  # scalar
 
     s = jax.lax.dot_general(
         qg, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale  # [G, block]
 
     # sequence position of each row of this table entry (by construction)
-    kp = ib * block + jax.lax.broadcasted_iota(jnp.int32, (block,), 0)
+    kp = ib * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
     mask = kp <= qp
     if window is not None:
         mask &= kp > qp - window
-    s = jnp.where(mask[None, :], s, NEG_INF)
+    s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_ref[...]  # [G, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.where(mask[None, :], jnp.exp(s - m_new[:, None]), 0.0)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     m_ref[...] = m_new
 
     @pl.when(ib == nb - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, 0, :, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        l = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -114,29 +123,30 @@ def paged_decode_attention(
     G = H // KV
     nb = block_table.shape[1]
 
-    kb = k_pool.reshape(-1, block, KV, hd)  # [n_blocks, block, KV, hd]
-    vb = v_pool.reshape(-1, block, KV, hd)
+    kf = k_pool.reshape(-1, KV * hd)  # lane-merged view, no copy
+    vf = v_pool.reshape(-1, KV * hd)
     # [B, 1, H, hd] -> [B, KV, G, hd]: one grid step covers a KV head group.
     qg = q[:, 0].reshape(B, KV, G, hd)
     tbl = block_table.astype(jnp.int32)
+    qp = q_pos.reshape(B).astype(jnp.int32)
 
     kernel = functools.partial(
         _kernel, nb=nb, block=block, window=window, scale=1.0 / (hd**0.5)
     )
+    kv_spec = pl.BlockSpec((block, hd), lambda b, h, ib, t, p: (t[b, ib], h))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(B, KV, nb),
         in_specs=[
-            pl.BlockSpec((1, 1, G, hd), lambda b, h, ib, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, block, 1, hd), lambda b, h, ib, t: (t[b, ib], 0, h, 0)),
-            pl.BlockSpec((1, block, 1, hd), lambda b, h, ib, t: (t[b, ib], 0, h, 0)),
-            pl.BlockSpec((1, 1), lambda b, h, ib, t: (b, 0)),
+            pl.BlockSpec((1, 1, G, hd), lambda b, h, ib, t, p: (b, h, 0, 0)),
+            kv_spec,
+            kv_spec,
         ],
-        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ib, t: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, G, hd), lambda b, h, ib, t, p: (b, h, 0, 0)),
         scratch_shapes=[
-            _scratch((G,), jnp.float32),
-            _scratch((G,), jnp.float32),
-            _scratch((G, hd), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((G, hd), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -144,5 +154,5 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
         interpret=interpret,
-    )(tbl, qg, kb, vb, q_pos)
+    )(tbl, qp, qg, kf, vf)
     return out.reshape(B, 1, H, hd)

@@ -22,8 +22,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from repro.kernels.flash_prefill import _scratch
+from jax.experimental.pallas import tpu as pltpu
 
 
 def supported(x, dt, A, B_, C, *, chunk: int = 256) -> bool:
@@ -140,7 +139,7 @@ def ssd_chunked(
             jax.ShapeDtypeStruct((Bsz, Lp, H, P), x.dtype),
             jax.ShapeDtypeStruct((Bsz, H, P, S), jnp.float32),
         ],
-        scratch_shapes=[_scratch((P, S), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((P, S), jnp.float32)],
         interpret=interpret,
     )(x, dt, Af, B_, C, h0)
     return y[:, :L], hT

@@ -5,14 +5,13 @@ from __future__ import annotations
 import jax
 
 
-def make_mesh_compat(shape, axes):
-    """``jax.make_mesh`` across jax versions: ``axis_types``/``AxisType``
-    first appeared after 0.4.x — pass explicit Auto types when the running
-    jax has them (the default there anyway), and omit the kwarg otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the model code places arrays with
+    sharding constraints, not explicit-sharding types (``jax.make_mesh``
+    defaults to ``Explicit``)."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -21,9 +20,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     axis is pure data-parallel (crosses DCI once per step)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
     """Small mesh over real local devices (tests / examples)."""
-    return make_mesh_compat((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

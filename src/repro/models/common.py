@@ -89,15 +89,9 @@ def init_stacked(key: jax.Array, n: int, init_one) -> Params:
 def maybe_shard(x: jax.Array, spec) -> jax.Array:
     """``with_sharding_constraint`` that no-ops when no mesh is active (so the
     same model code runs in single-device tests and in the dry-run)."""
-    if spec is None:
+    if spec is None or jax.sharding.get_abstract_mesh().empty:
         return x
-    try:
-        env = jax.sharding.get_abstract_mesh()
-        if env is None or env.empty:  # pragma: no cover - env dependent
-            return x
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:  # pragma: no cover - older jax fallbacks
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # --------------------------------------------------------------------------- #

@@ -31,17 +31,12 @@ def _ep_spec(cfg: ArchConfig):
     this constraint XLA partial-sums the expert matmuls over the model axis
     (observed: 8 x 32 GB all-reduce per Jamba train step — EXPERIMENTS.md
     §Perf hillclimb C)."""
-    try:
-        import jax.sharding as jsh
-
-        mesh = jsh.get_abstract_mesh()
-        if mesh is None or mesh.empty or "model" not in mesh.axis_names:
-            return None
-        m = mesh.shape["model"]
-        if m > 1 and cfg.moe.n_experts % m == 0:
-            return jsh.PartitionSpec("model", None, None)
-    except Exception:  # pragma: no cover
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or "model" not in mesh.axis_names:
         return None
+    m = mesh.shape["model"]
+    if m > 1 and cfg.moe.n_experts % m == 0:
+        return jax.sharding.PartitionSpec("model", None, None)
     return None
 
 

@@ -117,6 +117,7 @@ class ServingCluster:
         on_token=None,
         telemetry=None,
         market=None,
+        devices: Optional[Sequence[Any]] = None,
     ):
         self.cc = cluster_cfg or ClusterConfig()
         self.ec = engine_cfg or EngineConfig()
@@ -131,6 +132,12 @@ class ServingCluster:
         self.telemetry = telemetry
         n = self.cc.n_replicas
         assert n >= 1, n
+        # replica i's params and KV state live on devices[i] (one chip per
+        # replica); None keeps every replica on JAX's default device, all
+        # sharing one params tree
+        if devices is not None and len(devices) != n:
+            raise ValueError(f"{len(devices)} devices for {n} replicas")
+        self.devices = devices
 
         if self.ec.tier_specs is not None:
             specs = list(self.ec.tier_specs)
@@ -260,6 +267,7 @@ class ServingCluster:
             telemetry=self.telemetry,
             telemetry_replica=i,
             market=session,
+            device=None if self.devices is None else self.devices[i],
         )
 
     # ------------------------------------------------------------------ #

@@ -234,9 +234,14 @@ class ServingEngine:
         telemetry=None,
         telemetry_replica: int = 0,
         market=None,
+        device: Optional[jax.Device] = None,
     ):
         self.cfg = cfg
-        self.params = params
+        # The device this engine's params and KV state are committed to (a
+        # ServingCluster gives each replica its own); None = JAX's default.
+        # Host-built inputs follow the committed operands onto it.
+        self.device = device
+        self.params = params if device is None else jax.device_put(params, device)
         self.ec = engine_cfg or EngineConfig()
         self.pricing = pricing or tpu_v5e_pod(8)
         self.perf = perf or PerfModel(tpu_v5e(8, hosts=1))
@@ -354,16 +359,18 @@ class ServingEngine:
             self._paged = paged.PagedSlots(
                 self.ec.max_slots, self.ec.max_len, self.ec.kv_block
             )
-            self._pool_caches = paged.init_pool_caches(
-                cfg, self._paged.pool.n_blocks, self.ec.kv_block
+            self._pool_caches = self._on_device(
+                lambda: paged.init_pool_caches(
+                    cfg, self._paged.pool.n_blocks, self.ec.kv_block
+                )
             )
             self._jit_decode_paged = jax.jit(self._decode_paged_impl)
             # the paged path never touches the dense slotted cache: the pool
             # IS the device KV state (no doubled HBM footprint)
             self._state = None
         else:
-            self._state = self.api.init_state(
-                cfg, self.ec.max_slots, self.ec.max_len
+            self._state = self._on_device(
+                lambda: self.api.init_state(cfg, self.ec.max_slots, self.ec.max_len)
             )
         # Fused non-prefix reuse (CacheBlend-style): chunk-composite lookups
         # + the selective-recompute launch.  Needs the packed path's arch
@@ -427,6 +434,14 @@ class ServingEngine:
         self.market_purchases = 0  # plans served with bought peer KV
         self.market_failed = 0  # purchases that degraded to recompute
         self.market_spend = 0.0  # buyer dollars settled through the market
+
+    def _on_device(self, make):
+        """Build device state with ``make()`` on this engine's device,
+        committed there (as is when no device was given)."""
+        if self.device is None:
+            return make()
+        with jax.default_device(self.device):
+            return jax.device_put(make(), self.device)
 
     # ------------------------------------------------------------------ #
     # jit'd compute
@@ -511,7 +526,8 @@ class ServingEngine:
         else jump the clock to the next arrival.  A due migration pass
         (EngineConfig.migration_interval_s) piggybacks on the step and
         surfaces as TierMigrated events."""
-        events = self._step()
+        with jax.default_device(self.device):
+            events = self._step()
         if self.telemetry is not None and events:
             self.telemetry.on_events(events, replica=self._replica)
         return events
@@ -785,13 +801,6 @@ class ServingEngine:
             align=self.ec.pack_align,
             bucket_min=self.ec.pack_bucket_min,
         )
-        arrays = paged.pack_arrays(layout, [a.new_tokens for a in admissions])
-        caches = paged.build_packed_caches(
-            self.cfg, layout, [a.artifact for a in admissions]
-        )
-        last_idx = np.zeros((self.ec.max_slots,), np.int32)
-        for i, seg in enumerate(layout.segments):
-            last_idx[i] = seg.q_last
         jit_hit = self.jit_stats.record((layout.q_len, layout.kv_len))
         self.batches += 1
         self.packed_q_tokens += layout.q_tokens
@@ -805,16 +814,10 @@ class ServingEngine:
             )
         )
 
-        logits, new_caches = self._jit_packed(
-            self.params,
-            jnp.asarray(arrays["tokens"]),
-            caches,
-            jnp.asarray(arrays["q_pos"]),
-            jnp.asarray(arrays["q_seg"]),
-            jnp.asarray(arrays["q_rows"]),
-            jnp.asarray(arrays["kv_pos"]),
-            jnp.asarray(arrays["kv_seg"]),
-            jnp.asarray(last_idx),
+        logits, new_caches = self._packed_launch(
+            layout,
+            [a.new_tokens for a in admissions],
+            [a.artifact for a in admissions],
         )
 
         lens = [len(a.new_tokens) for a in admissions]
@@ -885,6 +888,48 @@ class ServingEngine:
                 self._c_gpu_s * prefill_s * (len(a.new_tokens) / total_new)
             )
             self._finish_admission(a, int(jnp.argmax(logits[i])), events)
+
+    def _packed_launch(self, layout: paged.PackLayout, new_tokens, artifacts):
+        """One packed ragged suffix-prefill launch: every segment's reused
+        prefix rows preloaded from its artifact (None = recompute), its new
+        tokens prefilled.  Returns (last-token logits per segment, packed
+        caches)."""
+        arrays = paged.pack_arrays(layout, new_tokens)
+        caches = paged.build_packed_caches(self.cfg, layout, artifacts)
+        last_idx = np.zeros((self.ec.max_slots,), np.int32)
+        for i, seg in enumerate(layout.segments):
+            last_idx[i] = seg.q_last
+        return self._jit_packed(
+            self.params,
+            jnp.asarray(arrays["tokens"]),
+            caches,
+            jnp.asarray(arrays["q_pos"]),
+            jnp.asarray(arrays["q_seg"]),
+            jnp.asarray(arrays["q_rows"]),
+            jnp.asarray(arrays["kv_pos"]),
+            jnp.asarray(arrays["kv_seg"]),
+            jnp.asarray(last_idx),
+        )
+
+    def prefill_logits(self, context_tokens, prompt_tokens, artifact=None) -> jax.Array:
+        """Logits [vocab] of the first output token after context + prompt,
+        computed by the packed admission launch (the default admission
+        path): the stored ``artifact``'s KV stands in for the whole context
+        and only the prompt is prefilled; ``None`` prefills everything.
+        Pure compute — no clock, store, slot or bill changes — so a caller
+        can check a reused request's logits against full recompute."""
+        if not self._packable:
+            raise ValueError(f"{self.cfg.name}: no packed admission path")
+        ctx, prompt = list(context_tokens), list(prompt_tokens)
+        matched = len(ctx) if artifact is not None else 0
+        new = ctx[matched:] + prompt
+        layout = paged.pack_layout(
+            [0], [matched], [len(new)],
+            align=self.ec.pack_align, bucket_min=self.ec.pack_bucket_min,
+        )
+        with jax.default_device(self.device):
+            logits, _ = self._packed_launch(layout, [new], [artifact])
+        return logits[0]
 
     # -- fused (chunk-composite) execution ------------------------------ #
     def _execute_fused(self, a: "_Admission", events: List[ev.Event]) -> None:

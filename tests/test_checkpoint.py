@@ -114,8 +114,8 @@ def test_elastic_reshape_restore(tmp_path):
 
     _, params, _ = _tiny()
     ckpt.save(tmp_path, 1, params)
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(1, 1)
     restored, _ = ckpt.restore(tmp_path, params)
     sharded = jax.tree_util.tree_map(
         lambda x: jax.device_put(x, NamedSharding(mesh, P())), restored

@@ -602,3 +602,35 @@ class TestDeltaGossip:
             self._check_equiv(cl)
         assert cl.gossip_full_syncs == full
         assert cl.gossip_delta_hashes == deltas
+
+
+def test_cluster_replica_devices():
+    """``devices=`` commits replica i's params and KV state to devices[i]
+    and serves what the default placement serves; a device count that does
+    not match the replica count is refused."""
+    import jax
+
+    cfg, params = ts._setup("qwen2-0.5b")
+    reqs = ts._requests(cfg, n=6, n_ctx=2, ctx_len=64, prompt_len=8, new=4, seed=0)
+    dev = jax.devices()[0]
+
+    def served(devices):
+        cl = ServingCluster(
+            cfg, params, cluster_cfg=ClusterConfig(n_replicas=2),
+            engine_cfg=_cluster_ec(), planner_factory=AlwaysReusePlanner,
+            devices=devices, **_paper_hw(),
+        )
+        for r in reqs:
+            cl.submit(Request(**r))
+        cl.run()
+        return cl, {rec.req_id: rec.tokens for rec in cl.records}
+
+    placed, tokens = served([dev, dev])
+    for e in placed.replicas:
+        assert e.device == dev
+        leaves = jax.tree_util.tree_leaves((e.params, e._state))
+        assert all(leaf.devices() == {dev} for leaf in leaves)
+    assert tokens == served(None)[1]
+    with pytest.raises(ValueError, match="1 devices for 2 replicas"):
+        ServingCluster(cfg, params, cluster_cfg=ClusterConfig(n_replicas=2),
+                       engine_cfg=_cluster_ec(), devices=[dev])

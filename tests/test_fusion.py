@@ -10,9 +10,9 @@ Four levels, mirroring the layering:
   * kernel  — ``ref.fused_prefill_ref`` equals plain causal attention at
     full query coverage (bitwise) and the Pallas kernel (interpret mode)
     agrees with the oracle on gappy multi-block shapes;
-  * model   — ``lm.prefill_fused`` at r=1.0 is bit-identical to a full
-    ``lm.prefill`` (logits AND caches); at r<1 reused rows pass through the
-    launch untouched;
+  * model   — ``lm.prefill_fused`` at r=1.0 matches a full ``lm.prefill``
+    (logits AND caches; bitwise, except qwen2 to f32 reordering tolerance);
+    at r<1 reused rows pass through the launch untouched (bitwise);
   * engine  — fused admissions at r=1.0 generate token-for-token what full
     recompute generates under dense AND paged decode; partial r serves with
     consistent counters/events; BlendPlanner gates on cost.
@@ -39,6 +39,22 @@ from repro.serving import (
 )
 from repro.serving import events as ev
 from repro.serving.planner import StoreLookup
+
+# The fused launch and a plain prefill run matmuls and reductions of
+# different shapes, which XLA may sum in a different order: f32 results
+# then differ in the last bits (observed <= 2e-6 at magnitudes <= 4 on the
+# CPU backend, ~1e-6 relative, after two layers).  2e-5 is ~10x that, and
+# far below the O(0.1) error a wrong position, row or mask causes.
+F32_REORDER_TOL = 2e-5
+
+# Of the reduced archs, only qwen2 (the one with q/k/v biases) is summed in a
+# different order by the two launches; the others still match bitwise
+# (tolerance 0: assert_allclose then demands |got - want| <= 0).
+R1_TOL = {"qwen2-1.5b": F32_REORDER_TOL}
+
+
+def assert_reorder_close(got, want, tol=F32_REORDER_TOL):
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol, atol=tol)
 
 
 # --------------------------------------------------------------------------- #
@@ -276,8 +292,9 @@ def _fused_launch(cfg, params, sched, ctx, prompt, sources):
 @pytest.mark.parametrize("arch", ["llama-7b", "qwen2-1.5b", "olmoe-1b-7b"])
 def test_model_fused_prefill_r1_bit_exact(arch):
     """lm.prefill_fused at recompute_frac=1.0 == a plain full lm.prefill of
-    the same sequence: last-token logits AND every context+prompt cache row,
-    bitwise — on a chunk-shuffled context the prefix path cannot serve."""
+    the same sequence: last-token logits AND every context+prompt cache row
+    (bitwise, or to ``R1_TOL[arch]``) — on a chunk-shuffled context the
+    prefix path cannot serve."""
     cfg, api, params = _setup(arch)
     rng = np.random.default_rng(2)
     chunk = 16
@@ -303,15 +320,12 @@ def test_model_fused_prefill_r1_bit_exact(arch):
     want, st_full = api.prefill(
         params, cfg, jnp.asarray([ctx_query + prompt], jnp.int32), st_full
     )
-    assert np.array_equal(np.asarray(logits[0]), np.asarray(want[0]))
+    tol = R1_TOL.get(arch, 0.0)
+    assert_reorder_close(logits[0], want[0], tol)
     n = layout.total
     for got_c, want_c in zip(new_caches, st_full.caches):
-        assert np.array_equal(
-            np.asarray(got_c.attn.k[:, :, :n]), np.asarray(want_c.attn.k[:, :, :n])
-        )
-        assert np.array_equal(
-            np.asarray(got_c.attn.v[:, :, :n]), np.asarray(want_c.attn.v[:, :, :n])
-        )
+        assert_reorder_close(got_c.attn.k[:, :, :n], want_c.attn.k[:, :, :n], tol)
+        assert_reorder_close(got_c.attn.v[:, :, :n], want_c.attn.v[:, :, :n], tol)
 
 
 def test_model_fused_prefill_partial_preserves_reused_rows():

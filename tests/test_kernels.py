@@ -218,3 +218,70 @@ def test_ssd_state_carry_equals_full_scan():
     )
     np.testing.assert_allclose(np.asarray(y2), np.asarray(y_full[:, half:]), atol=5e-5)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(hT_full), atol=5e-5)
+
+
+# --------------------------------------------------------------------------- #
+# ops dispatch under Pallas: kernel, explicit jnp rule, or an error
+# --------------------------------------------------------------------------- #
+@pytest.fixture
+def interpret_mode():
+    from repro.kernels import ops
+
+    ops.set_kernel_mode("pallas_interpret")
+    ops.reset_dispatch_counts()
+    yield ops
+    ops.set_kernel_mode(None)
+    ops.reset_dispatch_counts()
+
+
+def _dispatch_calls(ops, hd, S=128):
+    """op name -> a call of its ops entry point at head dim ``hd``."""
+    B, H, KV, blk = 2, 4, 2, 128
+    q, q1 = randn(B, S, H, hd), randn(B, 1, H, hd)
+    k, v = randn(B, S, KV, hd), randn(B, S, KV, hd)
+    pool = randn(2 * blk, KV, hd)
+    pos = ref.causal_positions(B, S)
+    tbl = jnp.asarray([[0], [1]], jnp.int32)
+    last = jnp.full((B, 1), S - 1, jnp.int32)
+    return {
+        "flash_prefill": lambda: ops.flash_attention(q, k, v, q_pos=pos, kv_pos=pos),
+        "packed_prefill": lambda: ops.packed_attention(
+            q, k, v, q_pos=pos, kv_pos=pos, q_seg=jnp.zeros_like(pos),
+            kv_seg=jnp.zeros_like(pos)),
+        "fused_prefill": lambda: ops.fused_prefill(q, k, v, q_pos=pos, kv_pos=pos),
+        "decode_attention": lambda: ops.decode_attention(
+            q1, k, v, q_pos=last, kv_pos=pos),
+        "paged_decode": lambda: ops.paged_decode(
+            q1, pool, pool, block_table=tbl, q_pos=last, block=blk),
+        "chunked_prefill": lambda: ops.chunked_prefill(
+            q[:, :8], pool, pool, block_table=tbl, q_pos=pos[:, :8], block=blk),
+    }
+
+
+@pytest.mark.parametrize("op", [
+    "flash_prefill", "packed_prefill", "fused_prefill", "decode_attention",
+    "paged_decode", "chunked_prefill",
+])
+def test_pallas_dispatch_raises_on_unsupported_shapes(interpret_mode, op):
+    """A head dim the kernel cannot tile (64: not lane-aligned) raises an
+    error naming the op and the operand shapes; it is never silently served
+    by the jnp reference."""
+    call = _dispatch_calls(interpret_mode, hd=64)[op]
+    with pytest.raises(interpret_mode.KernelUnsupported, match=rf"{op}: .*\[2, ") as e:
+        call()
+    assert "64]" in str(e.value)
+    assert interpret_mode.dispatch_counts() == {}
+
+
+def test_dispatch_counts_kernel_calls_and_short_prefill_rule(interpret_mode):
+    """Supported shapes take the kernel; a prefill shorter than MIN_KERNEL_Q
+    takes the jnp reference by the explicit rule; both are counted."""
+    ops = interpret_mode
+    calls = _dispatch_calls(ops, hd=128, S=ops.MIN_KERNEL_Q)
+    calls["decode_attention"]()
+    calls["flash_prefill"]()
+    _dispatch_calls(ops, hd=128, S=ops.MIN_KERNEL_Q // 2)["flash_prefill"]()
+    assert ops.dispatch_counts() == {
+        "decode_attention": {"kernel": 1, "jnp": 0},
+        "flash_prefill": {"kernel": 1, "jnp": 1},
+    }

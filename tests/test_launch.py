@@ -6,6 +6,7 @@ from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro.configs import ASSIGNED
@@ -95,3 +96,71 @@ def test_long500k_skips_are_exactly_the_full_attention_archs():
         "granite-34b", "mistral-nemo-12b", "qwen2-1.5b", "qwen2-0.5b",
         "whisper-tiny", "internvl2-1b", "olmoe-1b-7b",
     }
+
+
+# --------------------------------------------------------------------------- #
+# Serving launcher (repro.launch.serve) and the compile-cache helper
+# --------------------------------------------------------------------------- #
+@pytest.mark.parametrize("reduced", [True, False])
+def test_serve_launcher_full_width_setup(reduced):
+    """``--no-reduced`` serves the published config itself (its widths and
+    dtype) with no separate cost arch; the default computes on the reduced
+    config and models economics at the full arch."""
+    from repro.configs import get_config, reduced_config
+    from repro.launch import serve as launch
+
+    argv = ["--arch", "qwen2-1.5b"] + ([] if reduced else ["--no-reduced"])
+    s = launch.setup(launch.parse_args(argv), paged_decode=True)
+    full = get_config("qwen2-1.5b")
+    assert s.cfg == (reduced_config(full) if reduced else full)
+    assert s.engine_cfg.cost_arch == ("qwen2-1.5b" if reduced else None)
+    assert s.engine_cfg.paged_decode
+    # default max_len: the request's room (96+16+8+32) in whole kv blocks
+    assert s.engine_cfg.max_len == 256
+
+
+def test_serve_launcher_reused_logits_match_recompute():
+    """The launcher serves its workload with reuse, and a reused request's
+    first-token logits from its stored KV + suffix match a full-recompute
+    prefill (f32 reduced config: only summation order differs)."""
+    from repro.launch import serve as launch
+
+    args = launch.parse_args([
+        "--arch", "qwen2-1.5b", "--requests", "4", "--contexts", "1",
+        "--context-len", "64", "--prompt-len", "16", "--output-len", "2",
+        "--slots", "2", "--policy", "always",
+    ])
+    engine, summary = launch.serve(args)
+    assert summary.n_requests == 4 and summary.reuse_hits == 3
+    req = launch.workload(engine.cfg, args)[-1]
+    _, entry = engine.store.lookup(list(req.context_tokens))
+    artifact, _ = engine.store.fetch(entry.entry_id)
+    ctx, prompt = list(req.context_tokens), list(req.prompt_tokens)
+    reuse = np.asarray(engine.prefill_logits(ctx, prompt, artifact))
+    full = np.asarray(engine.prefill_logits(ctx, prompt))
+    assert reuse.shape == (engine.cfg.vocab,)
+    assert np.linalg.norm(reuse - full) <= 1e-5 * np.linalg.norm(full)
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is left to JAX; otherwise the
+    cache goes to the fixed .jax_cache/ at the repository root."""
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / env_dir))
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir is None:
+            root = Path(__file__).resolve().parents[1]
+            assert got == str(root / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

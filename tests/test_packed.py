@@ -1,4 +1,4 @@
-"""Packed ragged suffix-prefill: bit-exact parity with the per-request path.
+"""Packed ragged suffix-prefill: parity with the per-request path.
 
 Three levels, mirroring the layering:
 
@@ -6,7 +6,7 @@ Three levels, mirroring the layering:
     per-segment oracle, across MHA / GQA / sliding-window and partial-reuse
     offsets;
   * model   — ``lm.prefill_packed`` vs per-request ``lm.prefill`` over real
-    reduced archs (logits AND resulting caches, exact);
+    reduced archs (logits AND resulting caches, to f32 reordering tolerance);
   * engine  — batched admission vs ``admit_batch=1`` produces identical
     generations, emits multi-request BatchAdmitted events, spends strictly
     less modeled admission time, and reuses jit buckets (hit counters).
@@ -25,6 +25,20 @@ from repro.models import lm, registry
 from repro.serving import AlwaysReusePlanner, EngineConfig, Request, ServingEngine
 from repro.serving import events as ev
 from repro.serving.jit_cache import JitBucketStats
+
+# The packed and per-request launches run matmuls and reductions of
+# different shapes, which XLA may sum in a different order: f32 results
+# then differ in the last bits (observed <= 3e-6 at magnitudes <= 5 on the
+# CPU backend, ~1e-6 relative, after two layers).  2e-5 is ~10x that, and
+# far below the O(0.1) error a wrong mask, offset or segment id causes.
+F32_REORDER_TOL = 2e-5
+
+
+def assert_reorder_close(got, want):
+    np.testing.assert_allclose(
+        np.asarray(got), np.asarray(want),
+        rtol=F32_REORDER_TOL, atol=F32_REORDER_TOL,
+    )
 
 
 # --------------------------------------------------------------------------- #
@@ -88,8 +102,9 @@ def _pack_qkv(segs, H, KV, hd, align, seed=0):
     [(4, 4, None), (4, 2, None), (4, 2, 24)],  # MHA, GQA, GQA+sliding-window
 )
 def test_packed_ref_matches_per_segment_exactly(H, KV, window):
-    """Segment-masked packed attention == running each segment alone, bitwise,
-    across partial-reuse offsets (matched 0 / mid / full-prefix)."""
+    """Segment-masked packed attention == running each segment alone (to f32
+    reordering tolerance), across partial-reuse offsets (matched 0 / mid /
+    full-prefix)."""
     segs = [(0, 40), (32, 24), (56, 8)]
     per, packed = _pack_qkv(segs, H, KV, hd=16, align=64)
     out = ref.packed_attention_ref(
@@ -104,7 +119,7 @@ def test_packed_ref_matches_per_segment_exactly(H, KV, window):
             q_pos=jnp.asarray(s["q_pos"]), kv_pos=jnp.asarray(s["kv_pos"]),
             causal=True, window=window,
         )
-        assert np.array_equal(np.asarray(out[0, s["q_slice"]]), np.asarray(alone[0]))
+        assert_reorder_close(out[0, s["q_slice"]], alone[0])
 
 
 @pytest.mark.parametrize("H,KV,window", [(4, 4, None), (8, 2, None), (4, 2, 96)])
@@ -154,8 +169,9 @@ def _setup(arch, seed=0):
 @pytest.mark.parametrize("arch", ["llama-7b", "qwen2-1.5b", "olmoe-1b-7b"])
 def test_model_packed_prefill_bit_exact(arch):
     """lm.prefill_packed == per-request lm.prefill: last-token logits AND the
-    per-segment KV rows scattered back, bitwise, including a partial-reuse
-    segment whose prefix KV is preloaded from a stored artifact."""
+    per-segment KV rows scattered back (to f32 reordering tolerance),
+    including a partial-reuse segment whose prefix KV is preloaded from a
+    stored artifact."""
     cfg, api, params = _setup(arch)
     rng = np.random.default_rng(2)
     max_len = 128
@@ -190,17 +206,13 @@ def test_model_packed_prefill_bit_exact(arch):
         kv_seg=jnp.asarray(arrays["kv_seg"]),
         last_idx=jnp.asarray([s.q_last for s in layout.segments], jnp.int32),
     )
-    assert np.array_equal(np.asarray(logits[0]), np.asarray(lg0[0]))
-    assert np.array_equal(np.asarray(logits[1]), np.asarray(lg1[0]))
+    assert_reorder_close(logits[0], lg0[0])
+    assert_reorder_close(logits[1], lg1[0])
     for i, (st, n) in enumerate([(st0, 56), (st1, 56)]):
         got = paged.packed_to_artifact(cfg, new_caches, layout.segments[i], n)
         for c_got, c_want in zip(got.caches, st.caches):
-            assert np.array_equal(
-                np.asarray(c_got.attn.k), np.asarray(c_want.attn.k[:, :, :n])
-            )
-            assert np.array_equal(
-                np.asarray(c_got.attn.v), np.asarray(c_want.attn.v[:, :, :n])
-            )
+            assert_reorder_close(c_got.attn.k, c_want.attn.k[:, :, :n])
+            assert_reorder_close(c_got.attn.v, c_want.attn.v[:, :, :n])
 
 
 def test_pack_layout_alignment_and_buckets():

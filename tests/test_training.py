@@ -58,8 +58,7 @@ def test_compressed_pmean_error_bound():
     if len(devs) < 1:
         pytest.skip("no devices")
     # single-device axis: the compression round-trip itself must be tight
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,))
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
@@ -82,8 +81,8 @@ def test_opt_specs_add_zero1_sharding():
     from repro.models import registry as reg
 
     # abstract mesh is enough for spec construction
-    from repro.launch.mesh import make_mesh_compat
-    mesh = make_mesh_compat((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_host_mesh
+    mesh = make_host_mesh(1, 1)
     # emulate the production mesh's axis sizes for divisibility checks via a
     # fake object exposing .shape/.axis_names
     class FakeMesh:
