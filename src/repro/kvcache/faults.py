@@ -106,9 +106,13 @@ def payload_checksum(payload: Any) -> str:
     """
     import numpy as np
 
+    from repro.obs.host import span  # here: obs imports serving, which imports kvcache
+
     h = hashlib.blake2b(digest_size=16)
+    nbytes = 0
 
     def _walk(x: Any) -> None:
+        nonlocal nbytes
         if x is None:
             h.update(b"\x00N")
         elif isinstance(x, dict):
@@ -135,12 +139,15 @@ def payload_checksum(payload: Any) -> str:
                 h.update(b"\x00O")
                 h.update(type(x).__qualname__.encode())
             else:
+                nbytes += a.nbytes
                 h.update(b"\x00A")
                 h.update(str(a.dtype).encode())
                 h.update(repr(a.shape).encode())
                 h.update(a.tobytes())
 
-    _walk(payload)
+    with span("store.checksum") as sp:
+        _walk(payload)
+        sp.set(nbytes=nbytes)
     return h.hexdigest()
 
 

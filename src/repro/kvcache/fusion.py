@@ -415,6 +415,7 @@ def build_fused_caches(
     from repro.models.attention import KVCache
     from repro.models.blocks import BlockCache
     from repro.kvcache.paged import _attn_kinds
+    from repro.obs.host import span  # here: obs imports serving, which imports kvcache
 
     kinds, n_periods = _attn_kinds(cfg)
     dtype = dtype or common_mod.resolve_dtype(cfg.dtype)
@@ -438,7 +439,6 @@ def build_fused_caches(
             dst = slice(s.start, s.end)
             k_buf[:, 0, dst] = k_rows
             v_buf[:, 0, dst] = v_rows
-        out.append(
-            BlockCache(KVCache(jnp.asarray(k_buf), jnp.asarray(v_buf)), None)
-        )
+        with span("engine.h2d", nbytes=k_buf.nbytes + v_buf.nbytes):
+            out.append(BlockCache(KVCache(jnp.asarray(k_buf), jnp.asarray(v_buf)), None))
     return tuple(out)

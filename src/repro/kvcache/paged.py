@@ -277,6 +277,7 @@ def build_packed_caches(
     single transfer."""
     from repro.models import common as common_mod
     from repro.models.blocks import BlockCache
+    from repro.obs.host import span  # here: obs imports serving, which imports kvcache
 
     kinds, n_periods = _attn_kinds(cfg)
     dtype = dtype or common_mod.resolve_dtype(cfg.dtype)
@@ -297,9 +298,8 @@ def build_packed_caches(
             v_buf[:, :, rows] = np.asarray(
                 art.caches[ki].attn.v[:, :, : seg.matched], np_dtype
             )
-        out.append(
-            BlockCache(KVCache(jnp.asarray(k_buf), jnp.asarray(v_buf)), None)
-        )
+        with span("engine.h2d", nbytes=k_buf.nbytes + v_buf.nbytes):
+            out.append(BlockCache(KVCache(jnp.asarray(k_buf), jnp.asarray(v_buf)), None))
     return tuple(out)
 
 

@@ -17,6 +17,10 @@ scattered:
     prefill → decode → write-back) derived purely from the typed event
     stream, with cluster parent info (routing/rebalance) and a Chrome
     trace-event export loadable in Perfetto.
+  * ``host``      — measured host spans of the serving path on the
+    profiler's clock (``jax.profiler.TraceAnnotation``), kept in memory as
+    ``Span`` trees while the recorder is on; the SimClock spans above are
+    modeled, these are measured.
   * ``telemetry`` — the ``Telemetry`` facade engines/clusters accept:
     subscribes to the event stream, feeds all three pillars, and stays
     entirely host-side (telemetry on is token-identical to telemetry off,
@@ -25,6 +29,7 @@ scattered:
 Telemetry is OFF by default everywhere; pass ``telemetry=Telemetry()`` to
 ``ServingEngine``/``ServingCluster`` to turn it on.
 """
+from repro.obs import host
 from repro.obs.ledger import (
     CostLedger,
     LedgerEntry,
@@ -51,6 +56,7 @@ __all__ = [
     "build_spans",
     "check_conservation",
     "chrome_trace",
+    "host",
     "ledger_from_simulation",
     "write_chrome_trace",
 ]

@@ -33,6 +33,7 @@ import json
 import pathlib
 from typing import Dict, Iterable, List, Optional, Tuple, Union
 
+from repro.obs.host import HOST_PID
 from repro.serving import events as ev
 
 
@@ -304,14 +305,16 @@ def _span_events(s: Span) -> List[dict]:
 def chrome_trace(spans: List[Span]) -> dict:
     """Chrome trace-event JSON (the object form Perfetto/chrome://tracing
     load): one complete ("X") event per timed span, instants ("i") for the
-    zero-duration ones, pid = replica, tid = request."""
+    zero-duration ones, pid = replica (``HOST_PID`` for measured host
+    spans, ``obs.host``), tid = request."""
     events: List[dict] = []
     pids = sorted({s.replica for sp in spans for s in sp.walk()})
     for pid in pids:
         events.append(
             {
                 "ph": "M", "pid": pid, "tid": 0, "name": "process_name",
-                "args": {"name": f"replica {pid}"},
+                "args": {"name": "host spans (measured)" if pid == HOST_PID
+                         else f"replica {pid}"},
             }
         )
     for sp in spans:

@@ -54,7 +54,9 @@ from repro.kvcache.hierarchy import (
     build_backends,
 )
 from repro.kvcache.transfer import SimClock, TransferModel
+from repro.models import common as model_common
 from repro.models import registry
+from repro.obs.host import span
 from repro.serving import events as ev
 from repro.serving import metrics as metrics_mod
 from repro.serving.planner import (
@@ -217,6 +219,31 @@ class _ChunkStream:
         return len(self.tokens) - self.done
 
 
+# Host spans (``obs.host``): measured host time of each boundary of the
+# serving path, on the profiler's clock; docs/OBSERVABILITY.md has the table.
+def _ids(reqs) -> str:
+    """A batch span's ``req_ids``: space-separated (a profiler annotation
+    splits its metadata at commas)."""
+    return " ".join(str(r.req_id) for r in reqs)
+
+
+def _first_token(logits_row: jax.Array) -> int:
+    """The greedy token of one logits row, synced to the host."""
+    with span("engine.sync"):
+        return int(jnp.argmax(logits_row))
+
+
+def _to_host(make, parent: span) -> Any:
+    """The artifact ``make()`` gathers on the device, copied to host numpy;
+    its bytes label ``parent`` too."""
+    with span("engine.d2h") as sp:
+        out = jax.tree_util.tree_map(np.asarray, make())
+        nbytes = sum(x.nbytes for x in jax.tree_util.tree_leaves(out))
+        sp.set(nbytes=nbytes)
+    parent.set(nbytes=nbytes)
+    return out
+
+
 class ServingEngine:
     def __init__(
         self,
@@ -339,6 +366,13 @@ class ServingEngine:
         self._packable = (
             self.api.prefill_packed is not None
             and paged.packable_arch(cfg, self.ec.max_len)
+        )
+        # bytes of one KV row over every layer, K and V: the byte counters
+        # of a packed assembly's host span, from the config alone
+        self._kv_row_bytes = (
+            2 * cfg.n_layers * cfg.n_kv_heads * cfg.resolved_head_dim
+            * np.dtype(model_common.resolve_dtype(cfg.dtype)).itemsize
+            if self._packable else 0
         )
         # Paged batched decode over the shared KV block pool (packable archs
         # only — the paged layout needs per-position attention state and the
@@ -526,7 +560,7 @@ class ServingEngine:
         else jump the clock to the next arrival.  A due migration pass
         (EngineConfig.migration_interval_s) piggybacks on the step and
         surfaces as TierMigrated events."""
-        with jax.default_device(self.device):
+        with jax.default_device(self.device), span("engine.step"):
             events = self._step()
         if self.telemetry is not None and events:
             self.telemetry.on_events(events, replica=self._replica)
@@ -650,38 +684,39 @@ class ServingEngine:
         if not reqs:
             return False
 
-        # Plan sequentially, carrying each planned fetch's bytes forward so
-        # batch-mate i's predicted queue wait sees mates 0..i-1 on the same
-        # contended link — at execute time their reservations land in this
-        # order at one shared instant, and the planner must price that.
-        pending: Dict[str, List[float]] = {}
-        admissions: List[_Admission] = []
-        for req, slot in zip(reqs, free):
-            a = self._plan_admission(req, slot, events, pending=pending)
-            admissions.append(a)
-            if a.plan.action == "fused":
-                # pin every fusion source now: a batch-mate's write-back
-                # could otherwise evict it before the fused fetch executes
-                for eid in a.plan.fused.source_entries:
-                    if eid in self.store.entries:
-                        self.store.pin(eid)
-                        a.pins.append(eid)
-                # the fused fetches hit their tiers' links at the shared
-                # admission instant too: later batch-mates must price them
-                for tier, b in a.lookup.fused_bytes_by_tier.items():
-                    pending.setdefault(tier, []).append(b)
-            if a.plan.loads_kv and a.lookup.entry is not None:
-                pending.setdefault(a.lookup.entry.tier, []).append(
-                    self._entry_fetch_bytes(a.lookup.entry, a.plan.matched_tokens)
-                )
-        packed = [a for a in admissions if a.plan.action != "fused"]
-        if packed:
-            self._execute_packed(packed, events)
-        for a in admissions:
-            if a.plan.action == "fused":
-                self._execute_fused(a, events)
-        self._issue_prefetches()
-        return True
+        with span("engine.admit", n=len(reqs), req_ids=_ids(reqs)):
+            # Plan sequentially, carrying each planned fetch's bytes forward so
+            # batch-mate i's predicted queue wait sees mates 0..i-1 on the same
+            # contended link — at execute time their reservations land in this
+            # order at one shared instant, and the planner must price that.
+            pending: Dict[str, List[float]] = {}
+            admissions: List[_Admission] = []
+            for req, slot in zip(reqs, free):
+                a = self._plan_admission(req, slot, events, pending=pending)
+                admissions.append(a)
+                if a.plan.action == "fused":
+                    # pin every fusion source now: a batch-mate's write-back
+                    # could otherwise evict it before the fused fetch executes
+                    for eid in a.plan.fused.source_entries:
+                        if eid in self.store.entries:
+                            self.store.pin(eid)
+                            a.pins.append(eid)
+                    # the fused fetches hit their tiers' links at the shared
+                    # admission instant too: later batch-mates must price them
+                    for tier, b in a.lookup.fused_bytes_by_tier.items():
+                        pending.setdefault(tier, []).append(b)
+                if a.plan.loads_kv and a.lookup.entry is not None:
+                    pending.setdefault(a.lookup.entry.tier, []).append(
+                        self._entry_fetch_bytes(a.lookup.entry, a.plan.matched_tokens)
+                    )
+            packed = [a for a in admissions if a.plan.action != "fused"]
+            if packed:
+                self._execute_packed(packed, events)
+            for a in admissions:
+                if a.plan.action == "fused":
+                    self._execute_fused(a, events)
+            self._issue_prefetches()
+            return True
 
     def _plan_admission(
         self,
@@ -690,32 +725,35 @@ class ServingEngine:
         events: List[ev.Event],
         pending: Optional[Dict[str, List[float]]] = None,
     ):
-        rec = RequestRecord(
-            req_id=req.req_id,
-            arrival_s=req.arrival_s,
-            context_len=len(req.context_tokens),
-            prompt_len=len(req.prompt_tokens),
-            start_s=self.clock.now,
-        )
-        total_len = len(req.context_tokens) + len(req.prompt_tokens) + req.max_new_tokens
-        assert total_len <= self.ec.max_len, (total_len, self.ec.max_len)
-        events.append(
-            ev.RequestAdmitted(
-                t_s=self.clock.now, req_id=req.req_id, slot=slot.index,
-                queue_s=rec.queue_s,
+        with span("engine.plan", req=req.req_id):
+            rec = RequestRecord(
+                req_id=req.req_id,
+                arrival_s=req.arrival_s,
+                context_len=len(req.context_tokens),
+                prompt_len=len(req.prompt_tokens),
+                start_s=self.clock.now,
             )
-        )
-        lookup = self._lookup(req, pending)
-        workload = Workload(
-            L_context=len(req.context_tokens),
-            L_prompt=len(req.prompt_tokens),
-            L_output=req.max_new_tokens,
-            N=max(int(req.expected_reuses), 1),
-            slo_ttft_s=req.slo_ttft_s,
-        )
-        plan = self.planner.plan(req, lookup, workload)
-        events.append(ev.PlanChosen(t_s=self.clock.now, req_id=req.req_id, plan=plan))
-        return _Admission(req=req, rec=rec, slot=slot, plan=plan, lookup=lookup)
+            total_len = len(req.context_tokens) + len(req.prompt_tokens) + req.max_new_tokens
+            assert total_len <= self.ec.max_len, (total_len, self.ec.max_len)
+            events.append(
+                ev.RequestAdmitted(
+                    t_s=self.clock.now, req_id=req.req_id, slot=slot.index,
+                    queue_s=rec.queue_s,
+                )
+            )
+            with span("store.lookup", req=req.req_id) as sp:
+                lookup = self._lookup(req, pending)
+                sp.set(matched_tokens=lookup.match.matched_tokens if lookup.match else 0)
+            workload = Workload(
+                L_context=len(req.context_tokens),
+                L_prompt=len(req.prompt_tokens),
+                L_output=req.max_new_tokens,
+                N=max(int(req.expected_reuses), 1),
+                slo_ttft_s=req.slo_ttft_s,
+            )
+            plan = self.planner.plan(req, lookup, workload)
+            events.append(ev.PlanChosen(t_s=self.clock.now, req_id=req.req_id, plan=plan))
+            return _Admission(req=req, rec=rec, slot=slot, plan=plan, lookup=lookup)
 
     def _finish_admission(
         self, a: "_Admission", first_tok: int, events: List[ev.Event]
@@ -743,38 +781,36 @@ class ServingEngine:
 
     # -- per-request (fallback) execution ------------------------------- #
     def _admit_single(self, req: Request, slot: Slot, events: List[ev.Event]) -> bool:
-        a = self._plan_admission(req, slot, events)
-        if a.plan.market is not None:
-            self._market_fetch(a, events)
-        elif a.plan.loads_kv and a.lookup.entry is not None:
-            self._fetch_kv_resilient(a, events)
-        if a.artifact is not None:
-            load_s, prefill_s, logits, temp = self._execute_load(req, a, events)
-            matched = a.matched
-        else:
-            # plain recompute, or a degraded fetch falling back to exact
-            # recompute mid-admission — the burned fetch time rides on load_s
-            # (a.delay is 0.0 on the plain path)
-            load_s, matched = a.delay, 0
-            prefill_s, logits, temp = self._execute_recompute(req, a.plan, events)
-        self._release_prefetch(req.req_id)
+        with span("engine.admit", n=1, req_ids=str(req.req_id)):
+            a = self._plan_admission(req, slot, events)
+            if a.plan.market is not None:
+                self._market_fetch(a, events)
+            elif a.plan.loads_kv and a.lookup.entry is not None:
+                self._fetch_kv_resilient(a, events)
+            if a.artifact is not None:
+                load_s, prefill_s, logits, temp = self._execute_load(req, a, events)
+                matched = a.matched
+            else:
+                # plain recompute, or a degraded fetch falling back to exact
+                # recompute mid-admission — the burned fetch time rides on load_s
+                # (a.delay is 0.0 on the plain path)
+                load_s, matched = a.delay, 0
+                prefill_s, logits, temp = self._execute_recompute(req, a.plan, events)
+            self._release_prefetch(req.req_id)
 
-        # ---- install into the batch slot ------------------------------- #
-        if self._paged_on:
-            self._land_state_in_pool(slot, temp)
-        else:
-            self._state = paged.insert_slot(self.cfg, self._state, slot.index, temp)
-        first_tok = int(jnp.argmax(logits[0]))
+            # ---- install into the batch slot ------------------------------- #
+            self._land_state(slot, temp, req)
+            first_tok = _first_token(logits[0])
 
-        self.clock.advance(load_s + prefill_s)
-        self.admission_busy_s += load_s + prefill_s
-        a.rec.matched_tokens = matched
-        a.rec.load_s = load_s
-        a.rec.prefill_s = prefill_s
-        a.rec.compute_cost += self._c_gpu_s * prefill_s
-        self._finish_admission(a, first_tok, events)
-        self._issue_prefetches()
-        return True
+            self.clock.advance(load_s + prefill_s)
+            self.admission_busy_s += load_s + prefill_s
+            a.rec.matched_tokens = matched
+            a.rec.load_s = load_s
+            a.rec.prefill_s = prefill_s
+            a.rec.compute_cost += self._c_gpu_s * prefill_s
+            self._finish_admission(a, first_tok, events)
+            self._issue_prefetches()
+            return True
 
     # -- packed batch execution ----------------------------------------- #
     def _execute_packed(
@@ -818,6 +854,7 @@ class ServingEngine:
             layout,
             [a.new_tokens for a in admissions],
             [a.artifact for a in admissions],
+            jit_hit=jit_hit,
         )
 
         lens = [len(a.new_tokens) for a in admissions]
@@ -853,10 +890,10 @@ class ServingEngine:
                 if a.plan.store_after and tuple(a.req.context_tokens) not in written:
                     written.add(tuple(a.req.context_tokens))
                     ctx_len = len(a.req.context_tokens)
-                    art = paged.packed_to_artifact(self.cfg, new_caches, seg, ctx_len)
-                    self._write_back(
-                        a.req, jax.tree_util.tree_map(np.asarray, art), events
-                    )
+                    with span("engine.write_back", req=a.req.req_id) as sp:
+                        art = _to_host(lambda: paged.packed_to_artifact(
+                            self.cfg, new_caches, seg, ctx_len), sp)
+                        self._write_back(a.req, art, events)
             events.append(
                 ev.PrefillDone(
                     t_s=t0, req_id=a.req.req_id,
@@ -871,13 +908,16 @@ class ServingEngine:
         if self._paged_on:
             # packed outputs land DIRECTLY in the shared block pool: one
             # scatter for the whole batch, no per-slot re-materialization.
-            self._land_packed_in_pool(admissions, layout, new_caches)
+            with span("engine.land", req_ids=_ids(a.req for a in admissions),
+                      n_tokens=sum(seg.n_total for seg in layout.segments)):
+                self._land_packed_in_pool(admissions, layout, new_caches)
         for i, (a, seg) in enumerate(zip(admissions, layout.segments)):
             if not self._paged_on:
-                self._state = paged.insert_slot(
-                    self.cfg, self._state, seg.slot,
-                    paged.packed_to_artifact(self.cfg, new_caches, seg, seg.n_total),
-                )
+                with span("engine.land", req=a.req.req_id, n_tokens=seg.n_total):
+                    self._state = paged.insert_slot(
+                        self.cfg, self._state, seg.slot,
+                        paged.packed_to_artifact(self.cfg, new_caches, seg, seg.n_total),
+                    )
             a.rec.matched_tokens = a.matched
             # every batch member waits the load BARRIER (max of the batch's
             # fetches) before the shared kernel: record the realized wait so
@@ -887,29 +927,37 @@ class ServingEngine:
             a.rec.compute_cost += (
                 self._c_gpu_s * prefill_s * (len(a.new_tokens) / total_new)
             )
-            self._finish_admission(a, int(jnp.argmax(logits[i])), events)
+            self._finish_admission(a, _first_token(logits[i]), events)
 
-    def _packed_launch(self, layout: paged.PackLayout, new_tokens, artifacts):
+    def _packed_launch(self, layout: paged.PackLayout, new_tokens, artifacts,
+                       jit_hit: Optional[bool] = None):
         """One packed ragged suffix-prefill launch: every segment's reused
         prefix rows preloaded from its artifact (None = recompute), its new
         tokens prefilled.  Returns (last-token logits per segment, packed
-        caches)."""
-        arrays = paged.pack_arrays(layout, new_tokens)
-        caches = paged.build_packed_caches(self.cfg, layout, artifacts)
-        last_idx = np.zeros((self.ec.max_slots,), np.int32)
-        for i, seg in enumerate(layout.segments):
-            last_idx[i] = seg.q_last
-        return self._jit_packed(
-            self.params,
-            jnp.asarray(arrays["tokens"]),
-            caches,
-            jnp.asarray(arrays["q_pos"]),
-            jnp.asarray(arrays["q_seg"]),
-            jnp.asarray(arrays["q_rows"]),
-            jnp.asarray(arrays["kv_pos"]),
-            jnp.asarray(arrays["kv_seg"]),
-            jnp.asarray(last_idx),
-        )
+        caches).  ``jit_hit`` (the bucket's hit, where the caller recorded
+        it) only labels the launch's host span."""
+        row_bytes = self._kv_row_bytes
+        stored = sum(s.matched for s, art in zip(layout.segments, artifacts)
+                     if art is not None and s.matched > 0)
+        with span("engine.assemble", q_len=layout.q_len, kv_len=layout.kv_len,
+                  bucket_bytes=layout.kv_len * row_bytes,
+                  stored_bytes=stored * row_bytes):
+            arrays = paged.pack_arrays(layout, new_tokens)
+            caches = paged.build_packed_caches(self.cfg, layout, artifacts)
+            last_idx = np.zeros((self.ec.max_slots,), np.int32)
+            for i, seg in enumerate(layout.segments):
+                last_idx[i] = seg.q_last
+            index = [arrays[k] for k in ("tokens", "q_pos", "q_seg", "q_rows",
+                                         "kv_pos", "kv_seg")] + [last_idx]
+            with span("engine.h2d", nbytes=sum(x.nbytes for x in index)):
+                tokens, q_pos, q_seg, q_rows, kv_pos, kv_seg, last = map(
+                    jnp.asarray, index)
+        hit = {} if jit_hit is None else {"jit_hit": int(jit_hit)}
+        with span("engine.launch", program="packed_prefill", q_len=layout.q_len,
+                  kv_len=layout.kv_len, **hit):
+            return self._jit_packed(
+                self.params, tokens, caches, q_pos, q_seg, q_rows, kv_pos, kv_seg, last,
+            )
 
     def prefill_logits(self, context_tokens, prompt_tokens, artifact=None) -> jax.Array:
         """Logits [vocab] of the first output token after context + prompt,
@@ -959,20 +1007,23 @@ class ServingEngine:
             schedule, len(prompt),
             align=self.ec.pack_align, bucket_min=self.ec.pack_bucket_min,
         )
-        caches = fusion.build_fused_caches(
-            self.cfg, schedule, sources, layout.kv_len
-        )
-        arrays = fusion.fused_arrays(schedule, ctx, prompt, layout)
+        row_bytes = self._kv_row_bytes
+        with span("engine.assemble", q_len=layout.q_len, kv_len=layout.kv_len,
+                  bucket_bytes=layout.kv_len * row_bytes,
+                  stored_bytes=schedule.reused_tokens * row_bytes):
+            caches = fusion.build_fused_caches(
+                self.cfg, schedule, sources, layout.kv_len
+            )
+            arrays = fusion.fused_arrays(schedule, ctx, prompt, layout)
+            index = [arrays[k] for k in ("tokens", "q_pos", "q_rows", "kv_pos", "last_idx")]
+            with span("engine.h2d", nbytes=sum(x.nbytes for x in index)):
+                tokens, q_pos, q_rows, kv_pos, last = map(jnp.asarray, index)
         jit_hit = self.fused_jit.record((layout.q_len, layout.kv_len))
-        logits, new_caches = self._jit_fused(
-            self.params,
-            jnp.asarray(arrays["tokens"]),
-            caches,
-            jnp.asarray(arrays["q_pos"]),
-            jnp.asarray(arrays["q_rows"]),
-            jnp.asarray(arrays["kv_pos"]),
-            jnp.asarray(arrays["last_idx"]),
-        )
+        with span("engine.launch", program="fused_prefill", q_len=layout.q_len,
+                  kv_len=layout.kv_len, jit_hit=int(jit_hit)):
+            logits, new_caches = self._jit_fused(
+                self.params, tokens, caches, q_pos, q_rows, kv_pos, last,
+            )
 
         prefill_s = self.perf.t_prefill_fused(
             self.cost_cfg, layout.total, layout.n_q
@@ -1022,12 +1073,7 @@ class ServingEngine:
         art = paged.packed_to_artifact(
             self.cfg, new_caches, seg, min(n_rows, layout.kv_len)
         )._replace(pos=jnp.full((1,), layout.total, jnp.int32))
-        if self._paged_on:
-            self._land_state_in_pool(a.slot, art)
-        else:
-            self._state = paged.insert_slot(
-                self.cfg, self._state, a.slot.index, art
-            )
+        self._land_state(a.slot, art, req)
 
         self.clock.advance(load_s + prefill_s)
         self.admission_busy_s += load_s + prefill_s
@@ -1040,7 +1086,7 @@ class ServingEngine:
         a.rec.load_s = load_s
         a.rec.prefill_s = prefill_s
         a.rec.compute_cost += self._c_gpu_s * prefill_s
-        self._finish_admission(a, int(jnp.argmax(logits[0])), events)
+        self._finish_admission(a, _first_token(logits[0]), events)
 
     def _fetch_fused_sources(self, a: "_Admission", events: List[ev.Event]):
         """Fetch every fused source entry's matched rows (pinned at plan
@@ -1060,8 +1106,11 @@ class ServingEngine:
             nbytes = self._entry_fetch_bytes(e, rows)
             override = nbytes if self.cost_cfg is not self.cfg else None
 
-            def attempt(activity, eid=eid, e=e, rows=rows, override=override):
-                with self._attr(activity, req.req_id):
+            def attempt(activity, eid=eid, e=e, rows=rows, override=override,
+                        nbytes=nbytes):
+                with self._attr(activity, req.req_id), span(
+                    "store.fetch", req=req.req_id, tier=e.tier, nbytes=int(nbytes)
+                ):
                     return self.store.fetch(
                         eid, fraction=rows / max(e.n_tokens, 1), nbytes=override
                     )
@@ -1101,19 +1150,14 @@ class ServingEngine:
         the ground truth the fusion approximates from)."""
         req, wasted_s = a.req, a.delay
         prefill_s, logits, temp = self._execute_recompute(req, a.plan, events)
-        if self._paged_on:
-            self._land_state_in_pool(a.slot, temp)
-        else:
-            self._state = paged.insert_slot(
-                self.cfg, self._state, a.slot.index, temp
-            )
+        self._land_state(a.slot, temp, req)
         self.clock.advance(wasted_s + prefill_s)
         self.admission_busy_s += wasted_s + prefill_s
         a.rec.matched_tokens = 0
         a.rec.load_s = wasted_s
         a.rec.prefill_s = prefill_s
         a.rec.compute_cost += self._c_gpu_s * prefill_s
-        self._finish_admission(a, int(jnp.argmax(logits[0])), events)
+        self._finish_admission(a, _first_token(logits[0]), events)
 
     # -- shared-block-pool landings (paged decode) ---------------------- #
     def _pool_update(self, dst: np.ndarray, sources) -> None:
@@ -1170,6 +1214,16 @@ class ServingEngine:
             dst, ((nc.attn.k[:, 0, src], nc.attn.v[:, 0, src]) for nc in new_caches)
         )
 
+    def _land_state(self, slot: Slot, temp, req: Request) -> None:
+        """Install a freshly prefilled batch-1 state in ``slot``: the block
+        pool under paged decode, else the dense batched state."""
+        n_tokens = len(req.context_tokens) + len(req.prompt_tokens)
+        with span("engine.land", req=req.req_id, n_tokens=n_tokens):
+            if self._paged_on:
+                self._land_state_in_pool(slot, temp)
+            else:
+                self._state = paged.insert_slot(self.cfg, self._state, slot.index, temp)
+
     def _land_state_in_pool(self, slot: Slot, temp) -> None:
         """Per-request fallback admissions (embeds) under paged decode: copy
         the freshly prefilled batch-1 state's rows into newly allocated pool
@@ -1215,7 +1269,9 @@ class ServingEngine:
             # limited backends) is modeled at the same scale as the delay.
             nbytes = self._entry_fetch_bytes(entry, matched)
             override = nbytes
-        with self._attr(activity, req.req_id):
+        with self._attr(activity, req.req_id), span(
+            "store.fetch", req=req.req_id, tier=entry.tier, nbytes=int(nbytes)
+        ):
             artifact, delay = self.store.fetch(
                 entry.entry_id, fraction=matched / entry.n_tokens, nbytes=override
             )
@@ -1416,10 +1472,13 @@ class ServingEngine:
     def _write_back(self, req: Request, artifact: Any, events: List[ev.Event]) -> None:
         ctx = list(req.context_tokens)
         saved = self._c_gpu_s * self.perf.t_prefill(self.cost_cfg, len(ctx))
-        with self._attr("write_back", req.req_id):
-            entry_id, _ = self.store.put(
-                ctx, artifact, tier=self._store_tier(), saved_per_use=saved
-            )
+        tier = self._store_tier()
+        with self._attr("write_back", req.req_id), span(
+            "store.put", req=req.req_id, tier=tier
+        ) as sp:
+            entry_id, _ = self.store.put(ctx, artifact, tier=tier, saved_per_use=saved)
+            if entry_id is not None:
+                sp.set(nbytes=int(self.store.entries[entry_id].nbytes))
         h = self.store.last_put_handle if entry_id is not None else None
         if h is not None and h.dedup and self.market is not None:
             # KVShare multi-tenant dedup: another tenant already holds these
@@ -1552,7 +1611,7 @@ class ServingEngine:
         ctx = list(req.context_tokens)
         tail = [] if req.embeds is not None else ctx[matched:]
         tokens = jnp.asarray([tail + list(req.prompt_tokens)], jnp.int32)
-        logits, temp = self._jit_prefill(self.params, tokens, temp)
+        logits, temp = self._prefill_launch(tokens, temp)
         prefill_s = self.perf.t_prefill(
             self.cost_cfg, len(tail) + len(req.prompt_tokens)
         )
@@ -1578,6 +1637,11 @@ class ServingEngine:
         )
         return load_s, prefill_s, logits, temp
 
+    def _prefill_launch(self, tokens: jax.Array, state, **kw):
+        """One batch-1 prefill launch of the per-request path."""
+        with span("engine.launch", program="prefill", q_len=int(tokens.shape[1])):
+            return self._jit_prefill(self.params, tokens, state, **kw)
+
     def _execute_recompute(
         self, req: Request, plan: ReusePlan, events: List[ev.Event]
     ):
@@ -1585,30 +1649,30 @@ class ServingEngine:
         ctx, prompt = list(req.context_tokens), list(req.prompt_tokens)
         temp = self.api.init_state(self.cfg, 1, self.ec.max_len)
 
-        def write_back(artifact):
-            self._write_back(req, artifact, events)
+        def write_back(state):
+            with span("engine.write_back", req=req.req_id):
+                self._write_back(req, paged.extract_slot(self.cfg, state, 0, len(ctx)),
+                                 events)
 
         if req.embeds is not None:
             # VLM/audio context: the context IS the embeddings. Single
             # phase — positions [0, ctx) of the state depend only on the
             # embeds, so the artifact is extractable post-hoc.
             tokens = jnp.asarray([prompt], jnp.int32)
-            logits, temp = self._jit_prefill(
-                self.params, tokens, temp, embeds=req.embeds
-            )
+            logits, temp = self._prefill_launch(tokens, temp, embeds=req.embeds)
             if plan.store_after:
-                write_back(paged.extract_slot(self.cfg, temp, 0, len(ctx)))
+                write_back(temp)
         elif plan.store_after:
             # Two-phase: context-only prefill -> snapshot (valid for SSM
             # state, which must not include prompt tokens) -> prompt.
             ctx_tokens = jnp.asarray([ctx], jnp.int32)
-            _, temp = self._jit_prefill(self.params, ctx_tokens, temp)
-            write_back(paged.extract_slot(self.cfg, temp, 0, len(ctx)))
+            _, temp = self._prefill_launch(ctx_tokens, temp)
+            write_back(temp)
             tokens = jnp.asarray([prompt], jnp.int32)
-            logits, temp = self._jit_prefill(self.params, tokens, temp)
+            logits, temp = self._prefill_launch(tokens, temp)
         else:
             tokens = jnp.asarray([ctx + prompt], jnp.int32)
-            logits, temp = self._jit_prefill(self.params, tokens, temp)
+            logits, temp = self._prefill_launch(tokens, temp)
         prefill_s = self.perf.t_prefill(self.cost_cfg, len(ctx) + len(prompt))
         events.append(
             ev.PrefillDone(
@@ -1782,19 +1846,20 @@ class ServingEngine:
                 n += 1
                 admitted = True
                 continue
-            a = self._plan_admission(req, slot, events, pending=pending)
-            if a.plan.action == "fused":
-                for eid in a.plan.fused.source_entries:
-                    if eid in self.store.entries:
-                        self.store.pin(eid)
-                        a.pins.append(eid)
-                for tier, b in a.lookup.fused_bytes_by_tier.items():
-                    pending.setdefault(tier, []).append(b)
-            if a.plan.loads_kv and a.lookup.entry is not None:
-                pending.setdefault(a.lookup.entry.tier, []).append(
-                    self._entry_fetch_bytes(a.lookup.entry, a.plan.matched_tokens)
-                )
-            self._start_chunk_stream(a, events)
+            with span("engine.admit", n=1, req_ids=str(req.req_id)):
+                a = self._plan_admission(req, slot, events, pending=pending)
+                if a.plan.action == "fused":
+                    for eid in a.plan.fused.source_entries:
+                        if eid in self.store.entries:
+                            self.store.pin(eid)
+                            a.pins.append(eid)
+                    for tier, b in a.lookup.fused_bytes_by_tier.items():
+                        pending.setdefault(tier, []).append(b)
+                if a.plan.loads_kv and a.lookup.entry is not None:
+                    pending.setdefault(a.lookup.entry.tier, []).append(
+                        self._entry_fetch_bytes(a.lookup.entry, a.plan.matched_tokens)
+                    )
+                self._start_chunk_stream(a, events)
             n += 1
             admitted = True
         if admitted:
@@ -1832,22 +1897,27 @@ class ServingEngine:
                 schedule, len(prompt),
                 align=self.ec.pack_align, bucket_min=self.ec.pack_bucket_min,
             )
-            caches = fusion.build_fused_caches(
-                self.cfg, schedule, sources, layout.kv_len
-            )
+            row_bytes = self._kv_row_bytes
+            with span("engine.assemble", q_len=layout.q_len, kv_len=layout.kv_len,
+                      bucket_bytes=layout.kv_len * row_bytes,
+                      stored_bytes=schedule.reused_tokens * row_bytes):
+                caches = fusion.build_fused_caches(
+                    self.cfg, schedule, sources, layout.kv_len
+                )
             # land the whole assembled buffer's valid rows: reuse spans
             # carry stored (delta-RoPE'd) KV, recompute/prompt rows are
             # zero and get overwritten as their chunk tokens land
             rows = paged.block_rows(
                 ps.tables[a.slot.index, : len(own)], block
             )[:n_total]
-            self._pool_update(
-                rows,
-                (
-                    (c.attn.k[:, 0, :n_total], c.attn.v[:, 0, :n_total])
-                    for c in caches
-                ),
-            )
+            with span("engine.land", req=req.req_id, n_tokens=n_total):
+                self._pool_update(
+                    rows,
+                    (
+                        (c.attn.k[:, 0, :n_total], c.attn.v[:, 0, :n_total])
+                        for c in caches
+                    ),
+                )
             arrays = fusion.fused_arrays(schedule, ctx, prompt, layout)
             tokens = np.asarray(arrays["tokens"][0, : layout.n_q], np.int32)
             positions = np.asarray(arrays["q_pos"][0, : layout.n_q], np.int32)
@@ -1874,16 +1944,17 @@ class ServingEngine:
             rows = paged.block_rows(
                 ps.tables[a.slot.index, : -(-matched // block)], block
             )[:matched]
-            self._pool_update(
-                rows,
-                (
+            with span("engine.land", req=req.req_id, n_tokens=matched):
+                self._pool_update(
+                    rows,
                     (
-                        jnp.asarray(c.attn.k[:, 0, :matched]),
-                        jnp.asarray(c.attn.v[:, 0, :matched]),
-                    )
-                    for c in a.artifact.caches
-                ),
-            )
+                        (
+                            jnp.asarray(c.attn.k[:, 0, :matched]),
+                            jnp.asarray(c.attn.v[:, 0, :matched]),
+                        )
+                        for c in a.artifact.caches
+                    ),
+                )
             events.append(ev.KVLoaded(
                 t_s=t0, req_id=req.req_id,
                 tier=(
@@ -1931,7 +2002,9 @@ class ServingEngine:
                 self._decode_step(events)
                 return True
             return False
-        self._unified_mixed_step(ready, events)
+        with span("engine.decode", n_active=sum(s.active for s in self.slots),
+                  n_chunks=len(ready)):
+            self._unified_mixed_step(ready, events)
         return True
 
     def _unified_mixed_step(
@@ -1984,10 +2057,12 @@ class ServingEngine:
             chunk_desc.append((g, int(c.positions[c.done + g - 1]) + 1))
 
         jit_hit = self.unified_jit.record((B, C, ps.nb_max))
-        logits, self._pool_caches = self._jit_chunked(
-            self.params, jnp.asarray(toks), self._pool_caches,
-            jnp.asarray(ps.tables), jnp.asarray(q_pos), jnp.asarray(last_idx),
-        )
+        with span("engine.launch", program="chunked_prefill", q_len=C,
+                  jit_hit=int(jit_hit)):
+            logits, self._pool_caches = self._jit_chunked(
+                self.params, jnp.asarray(toks), self._pool_caches,
+                jnp.asarray(ps.tables), jnp.asarray(q_pos), jnp.asarray(last_idx),
+            )
         for s in decoding:
             ps.note_token(s.index)
 
@@ -2014,22 +2089,24 @@ class ServingEngine:
             step_s=step_s, jit_hit=jit_hit,
         ))
 
-        nxt_tok = np.asarray(jnp.argmax(logits, axis=-1))
-        for s, share in zip(decoding, dec_sh):
-            tok = int(nxt_tok[s.index])
-            s.record.tokens.append(tok)
-            s.record.decode_s += step_s
-            s.record.compute_cost += self._c_gpu_s * step_s * share
-            s.last_token = tok
-            tok_ev = ev.TokenEmitted(
-                t_s=self.clock.now, req_id=s.request.req_id,
-                token=tok, index=s.generated,
-            )
-            events.append(tok_ev)
-            if self.on_token is not None:
-                self.on_token(tok_ev)
-            s.generated += 1
-            self._maybe_finish(s, events)
+        with span("engine.sync"):
+            nxt_tok = np.asarray(jnp.argmax(logits, axis=-1))
+        with span("engine.emit", n_tokens=len(decoding)):
+            for s, share in zip(decoding, dec_sh):
+                tok = int(nxt_tok[s.index])
+                s.record.tokens.append(tok)
+                s.record.decode_s += step_s
+                s.record.compute_cost += self._c_gpu_s * step_s * share
+                s.last_token = tok
+                tok_ev = ev.TokenEmitted(
+                    t_s=self.clock.now, req_id=s.request.req_id,
+                    token=tok, index=s.generated,
+                )
+                events.append(tok_ev)
+                if self.on_token is not None:
+                    self.on_token(tok_ev)
+                s.generated += 1
+                self._maybe_finish(s, events)
         for (c, g), share in zip(grants, chk_sh):
             a = c.a
             a.rec.compute_cost += self._c_gpu_s * step_s * share
@@ -2040,8 +2117,10 @@ class ServingEngine:
             if self._wb_inflight.get(tuple(a.req.context_tokens)) == a.slot.index:
                 self._wb_inflight.pop(tuple(a.req.context_tokens))
             if c.store_after:
-                art = self._pool_slot_artifact(a.slot.index, c.n_ctx)
-                self._write_back(a.req, art, events)
+                with span("engine.write_back", req=a.req.req_id) as sp:
+                    art = _to_host(lambda: self._pool_slot_artifact(
+                        a.slot.index, c.n_ctx), sp)
+                    self._write_back(a.req, art, events)
             a.rec.matched_tokens = a.matched
             a.rec.load_s = a.delay
             # ttft_s = queue_s + load_s + prefill_s must equal the first
@@ -2095,62 +2174,66 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     def _decode_step(self, events: List[ev.Event]) -> None:
         active = np.array([s.active for s in self.slots])
-        toks = np.array(
-            [[s.last_token if s.active else 0] for s in self.slots], np.int32
-        )
-        if self._paged_on:
-            logits = self._decode_paged_launch(toks)
-        else:
-            logits, self._state = self._jit_decode(
-                self.params, jnp.asarray(toks), self._state, jnp.asarray(active)
-            )
         n_active = int(active.sum())
-        lens = [
-            s.record.context_len + s.record.prompt_len + s.generated
-            for s in self.slots
-            if s.active
-        ]
-        if self._paged_on:
-            # live-blocks pricing: each slot is billed exactly the KV bytes
-            # its block table streams, not the longest slot's padded length.
-            step_s = self.perf.t_decode_paged(self.cost_cfg, lens)
-        else:
-            step_s = self.perf.t_decode(self.cost_cfg, 1, max(lens), batch=n_active)
-        self.decode_busy_s += step_s
-        self.decode_tokens += n_active
-        self.clock.advance(step_s)
-        if self._paged_on:
-            # bill each slot proportional to the KV bytes its own live
-            # blocks stream through the step, not an equal split — a
-            # short-context slot no longer subsidizes a long batch-mate.
-            # Uniform lengths give equal weights, so this agrees with the
-            # dense split exactly in the uniform case.  The weights are
-            # normalized, so the split conserves the step's dollars.
-            w = [self.perf.decode_kv_bytes(self.cost_cfg, l) for l in lens]
-            total_w = sum(w)
-            costs = [self._c_gpu_s * step_s * wi / total_w for wi in w]
-        else:
-            costs = [self._c_gpu_s * step_s / n_active] * n_active
-
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        cost_it = iter(costs)
-        for s in self.slots:
-            if not s.active:
-                continue
-            tok = int(nxt[s.index])
-            s.record.tokens.append(tok)
-            s.record.decode_s += step_s
-            s.record.compute_cost += next(cost_it)
-            s.last_token = tok
-            tok_ev = ev.TokenEmitted(
-                t_s=self.clock.now, req_id=s.request.req_id,
-                token=tok, index=s.generated,
+        with span("engine.decode", n_active=n_active):
+            toks = np.array(
+                [[s.last_token if s.active else 0] for s in self.slots], np.int32
             )
-            events.append(tok_ev)
-            if self.on_token is not None:
-                self.on_token(tok_ev)
-            s.generated += 1
-            self._maybe_finish(s, events)
+            if self._paged_on:
+                logits = self._decode_paged_launch(toks)
+            else:
+                with span("engine.launch", program="decode"):
+                    logits, self._state = self._jit_decode(
+                        self.params, jnp.asarray(toks), self._state, jnp.asarray(active)
+                    )
+            lens = [
+                s.record.context_len + s.record.prompt_len + s.generated
+                for s in self.slots
+                if s.active
+            ]
+            if self._paged_on:
+                # live-blocks pricing: each slot is billed exactly the KV bytes
+                # its block table streams, not the longest slot's padded length.
+                step_s = self.perf.t_decode_paged(self.cost_cfg, lens)
+            else:
+                step_s = self.perf.t_decode(self.cost_cfg, 1, max(lens), batch=n_active)
+            self.decode_busy_s += step_s
+            self.decode_tokens += n_active
+            self.clock.advance(step_s)
+            if self._paged_on:
+                # bill each slot proportional to the KV bytes its own live
+                # blocks stream through the step, not an equal split — a
+                # short-context slot no longer subsidizes a long batch-mate.
+                # Uniform lengths give equal weights, so this agrees with the
+                # dense split exactly in the uniform case.  The weights are
+                # normalized, so the split conserves the step's dollars.
+                w = [self.perf.decode_kv_bytes(self.cost_cfg, l) for l in lens]
+                total_w = sum(w)
+                costs = [self._c_gpu_s * step_s * wi / total_w for wi in w]
+            else:
+                costs = [self._c_gpu_s * step_s / n_active] * n_active
+
+            with span("engine.sync"):
+                nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            cost_it = iter(costs)
+            with span("engine.emit", n_tokens=n_active):
+                for s in self.slots:
+                    if not s.active:
+                        continue
+                    tok = int(nxt[s.index])
+                    s.record.tokens.append(tok)
+                    s.record.decode_s += step_s
+                    s.record.compute_cost += next(cost_it)
+                    s.last_token = tok
+                    tok_ev = ev.TokenEmitted(
+                        t_s=self.clock.now, req_id=s.request.req_id,
+                        token=tok, index=s.generated,
+                    )
+                    events.append(tok_ev)
+                    if self.on_token is not None:
+                        self.on_token(tok_ev)
+                    s.generated += 1
+                    self._maybe_finish(s, events)
 
     def _decode_paged_launch(self, toks: np.ndarray) -> jax.Array:
         """One paged decode launch across all active slots: grow/CoW-split
@@ -2166,10 +2249,11 @@ class ServingEngine:
                     splits.append(cow)
         if splits:
             self._copy_pool_blocks(splits)
-        logits, self._pool_caches = self._jit_decode_paged(
-            self.params, jnp.asarray(toks), self._pool_caches,
-            jnp.asarray(ps.tables), jnp.asarray(ps.lens, jnp.int32),
-        )
+        with span("engine.launch", program="decode_paged"):
+            logits, self._pool_caches = self._jit_decode_paged(
+                self.params, jnp.asarray(toks), self._pool_caches,
+                jnp.asarray(ps.tables), jnp.asarray(ps.lens, jnp.int32),
+            )
         for s in self.slots:
             if s.active:
                 ps.note_token(s.index)
