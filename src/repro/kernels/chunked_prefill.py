@@ -16,7 +16,8 @@ a scalar-prefetch operand so the k/v BlockSpec index maps DMA pool block
 processed together, so each pool block is read once per head group.
 
 TPU tiling: the pool is viewed lane-merged as ``[N_rows, KV*hd]`` and the
-queries as ``[B, C, H*hd]`` (both free reshapes).  A KV head's G query heads
+queries as ``[B, C, H*hd]`` (the pool's view is a relayout copy of the whole
+pool on the TPU, as in ``paged_decode``).  A KV head's G query heads
 are adjacent in the head axis, so one query block is ``(C, G*hd)`` at
 ``(b, 0, h)`` and the kernel walks the G heads as static ``hd``-wide lane
 slices; the output is written back in the same layout, so no transpose
@@ -127,7 +128,7 @@ def chunked_prefill_attention(
     G = H // KV
     nb = block_table.shape[1]
 
-    kf = k_pool.reshape(-1, KV * hd)  # lane-merged views, no copy
+    kf = k_pool.reshape(-1, KV * hd)  # lane-merged views: a relayout copy on TPU
     vf = v_pool.reshape(-1, KV * hd)
     qf = q.reshape(B, C, H * hd)
     tbl = block_table.astype(jnp.int32)

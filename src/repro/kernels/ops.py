@@ -206,24 +206,30 @@ def fused_prefill(
 
 def decode_attention(
     q: jax.Array,  # [B, 1, H, hd]
-    k: jax.Array,  # [B, L, KV, hd]
+    k: jax.Array,  # [n_layers, B, L, KV, hd] — the stacked cache
     v: jax.Array,
     *,
+    layer: jax.Array,  # int32 scalar — the layer of k/v to attend
     q_pos: jax.Array,
     kv_pos: jax.Array,
     window: Optional[int] = None,
     kv_valid: Optional[jax.Array] = None,
 ) -> jax.Array:
+    """One query per sequence against layer ``layer`` of a stacked cache.
+    The kernel reads that layer where it lies; the jnp reference indexes it
+    out (a copy, which the CPU path does not mind)."""
     use_pallas, interpret = _use_pallas()
     if use_pallas:
         from repro.kernels import decode_attention as dk
 
         _kernel("decode_attention", dk.supported(q, k, v), q, k, v)
         return dk.decode_attention(
-            q, k, v, q_pos=q_pos, kv_pos=kv_pos, window=window, kv_valid=kv_valid,
-            interpret=interpret,
+            q, k, v, layer=layer, q_pos=q_pos, kv_pos=kv_pos, window=window,
+            kv_valid=kv_valid, interpret=interpret,
         )
     _jnp("decode_attention")
+    k = jax.lax.dynamic_index_in_dim(k, layer, keepdims=False)
+    v = jax.lax.dynamic_index_in_dim(v, layer, keepdims=False)
     if kv_shard_enabled() and kv_valid is None:
         out = _kv_sharded_attention(
             q, k, v, q_pos=q_pos, kv_pos=kv_pos, causal=True, window=window

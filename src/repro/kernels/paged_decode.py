@@ -12,11 +12,14 @@ dereference it — the DMA for grid step (b, h, j) fetches pool block
 ``table[b, j]`` directly; no gathered copy of the cache is ever
 materialised.
 
-TPU tiling: the pool is viewed lane-merged as ``[N_rows, KV*hd]`` (a free
-reshape of the contiguous array), so one grid step's k/v block is
+TPU tiling: the pool is viewed lane-merged as ``[N_rows, KV*hd]``, so one
+grid step's k/v block is
 ``(block, hd)`` at block index ``(table[b, j], h)`` — both minor dims are
-(8, 128)-aligned when ``hd % 128 == 0``.  The query positions ride in SMEM
-as a second scalar-prefetch operand.
+(8, 128)-aligned when ``hd % 128 == 0``.  The view is not free on the TPU:
+the pool is stored in ``(KV, hd)`` tiles per row, so the compiler copies the
+whole pool into the merged layout before each call (the dense decode kernel
+reads ``(bkv, KV, hd)`` blocks instead; see ``decode_attention``).  The query
+positions ride in SMEM as a second scalar-prefetch operand.
 
 Grid (B, KV, nb) with the G grouped query heads of a KV head processed
 together (the cache block is read once per head group), flash-style running
@@ -123,7 +126,7 @@ def paged_decode_attention(
     G = H // KV
     nb = block_table.shape[1]
 
-    kf = k_pool.reshape(-1, KV * hd)  # lane-merged view, no copy
+    kf = k_pool.reshape(-1, KV * hd)  # lane-merged view: a relayout copy on TPU
     vf = v_pool.reshape(-1, KV * hd)
     # [B, 1, H, hd] -> [B, KV, G, hd]: one grid step covers a KV head group.
     qg = q[:, 0].reshape(B, KV, G, hd)

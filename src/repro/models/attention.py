@@ -7,12 +7,14 @@ Three call modes share one weight set:
                    ``offset > 0`` this is the paper's *suffix prefill*: the
                    reused context KV occupying ``[0, offset)`` is NOT
                    recomputed.
-  * ``decode``   — one token per sequence against the cache (ring-buffer
-                   indexing for sliding-window attention).
+  * ``decode``   — one token per sequence against one layer of the
+                   stacked cache, updated in place (ring-buffer indexing
+                   for sliding-window attention).
 
 Cache layout (TPU-native slotted dense cache, see DESIGN.md §3):
   k/v: [B, L_cache, KV_heads, head_dim]
-where ``L_cache = min(max_len, window)`` for SWA archs.
+where ``L_cache = min(max_len, window)`` for SWA archs; a decode state
+stacks it over layers, ``[n_layers, B, L_cache, KV_heads, head_dim]``.
 """
 from __future__ import annotations
 
@@ -243,11 +245,19 @@ def decode(
     p: Params,
     cfg: ArchConfig,
     x: jax.Array,  # [B, 1, D]
-    cache: KVCache,
+    cache: KVCache,  # k/v: [n_layers, B, L_cache, KV, hd] — every layer's cache
+    layer: jax.Array,  # int32 scalar — this layer's index into ``cache``
     pos: jax.Array,  # [B] int32 — position of this token (== cached length)
 ) -> Tuple[jax.Array, KVCache]:
+    """One token per sequence against layer ``layer`` of the stacked cache.
+
+    The cache is updated in place: the B new K/V rows are scattered to
+    ``(layer, b, pos[b])`` (``pos % window`` for a ring buffer), and the
+    attention reads the layer where it lies (``ops.decode_attention``), so
+    no per-layer slice of the cache is taken or written back.
+    """
     B = x.shape[0]
-    L = cache.k.shape[1]
+    L = cache.k.shape[2]
     q, k_new, v_new = _qkv(p, cfg, x)
     positions = pos[:, None]  # [B, 1]
     if cfg.rope_theta is not None:
@@ -255,20 +265,19 @@ def decode(
         k_new = apply_rope(k_new, positions, cfg.rope_theta)
 
     if cfg.sliding_window and L == cfg.sliding_window:
-        slots = positions % cfg.sliding_window
-        cache = KVCache(
-            _scatter_rows(cache.k, slots, k_new), _scatter_rows(cache.v, slots, v_new)
-        )
+        rows = pos % cfg.sliding_window
         kv_pos = _ring_positions(pos + 1, L, B)
     else:
-        cache = KVCache(
-            _scatter_rows(cache.k, positions, k_new), _scatter_rows(cache.v, positions, v_new)
-        )
+        rows = pos
         idx = jnp.arange(L, dtype=jnp.int32)[None]
         kv_pos = jnp.where(idx <= pos[:, None], idx, -1)
-
+    cache = KVCache(
+        _write_layer_rows(cache.k, layer, rows, k_new[:, 0]),
+        _write_layer_rows(cache.v, layer, rows, v_new[:, 0]),
+    )
     o = ops.decode_attention(
-        q, cache.k, cache.v, q_pos=positions, kv_pos=kv_pos, window=cfg.sliding_window
+        q, cache.k, cache.v, layer=layer, q_pos=positions, kv_pos=kv_pos,
+        window=cfg.sliding_window,
     )
     return _out(p, o), cache
 
@@ -404,6 +413,17 @@ def _write_rows(cache: jax.Array, offset: jax.Array, new: jax.Array) -> jax.Arra
         return jax.lax.dynamic_update_slice(c, n, (o,) + (0,) * (c.ndim - 1))
 
     return jax.vmap(per_seq)(cache, offset.astype(jnp.int32), new)
+
+
+def _write_layer_rows(
+    cache: jax.Array, layer: jax.Array, rows: jax.Array, new: jax.Array
+) -> jax.Array:
+    """Write ``new`` [B, ...] into the stacked ``cache`` [n_layers, B, L, ...]
+    at ``(layer, b, rows[b])``: one scatter of B rows, in place under jit."""
+    B = new.shape[0]
+    return cache.at[layer, jnp.arange(B), rows.astype(jnp.int32)].set(
+        new.astype(cache.dtype)
+    )
 
 
 def _scatter_rows(cache: jax.Array, slots: jax.Array, new: jax.Array) -> jax.Array:

@@ -89,7 +89,7 @@ def forward(
 
 
 # --------------------------------------------------------------------------- #
-# Prefill / decode with per-layer cache slices
+# Prefill with per-layer cache slices; decode against the stacked caches
 # --------------------------------------------------------------------------- #
 class BlockCache(NamedTuple):
     """Union cache for one layer; unused member is None (static per kind)."""
@@ -228,16 +228,28 @@ def decode(
     cfg: ArchConfig,
     kind: BlockKind,
     x: jax.Array,
-    cache: BlockCache,
+    cache: BlockCache,  # every period's cache of this kind, stacked
+    layer: jax.Array,  # int32 scalar — this block's period index
     pos: jax.Array,  # [B]
 ) -> Tuple[jax.Array, BlockCache]:
+    """Decode one block against the stacked cache, returning the stack with
+    this block's entry updated: attention writes its new rows in place
+    (``attention.decode``); the O(1) SSM state is indexed out and written
+    back."""
     h = layers.apply_norm(p["norm1"], cfg, x)
     if kind.mixer == "a":
-        out, kv = attention.decode(p["attn"], cfg, h, cache.attn, pos)
+        out, kv = attention.decode(p["attn"], cfg, h, cache.attn, layer, pos)
         cache = BlockCache(kv, None)
     else:
-        out, st = ssm.decode(p["mamba"], cfg, h, cache.mamba)
-        cache = BlockCache(None, st)
+        st = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            cache.mamba,
+        )
+        out, st = ssm.decode(p["mamba"], cfg, h, st)
+        cache = BlockCache(None, jax.tree_util.tree_map(
+            lambda a, s: jax.lax.dynamic_update_index_in_dim(a, s, layer, 0),
+            cache.mamba, st,
+        ))
     x = x + out
     x, _ = _apply_ffn(p, cfg, kind, x)
     return x, cache

@@ -199,7 +199,11 @@ def decode(
     def layer_fn(x, per):
         lp, kv, ckv = per
         h = layers.apply_norm(lp["norm1"], cfg, x)
-        out, kv = attention.decode(lp["self_attn"], cfg, h, kv, pos)
+        # a one-layer stack: attention.decode takes the stacked layout
+        out, kv = attention.decode(
+            lp["self_attn"], cfg, h, attention.KVCache(kv.k[None], kv.v[None]), 0, pos
+        )
+        kv = attention.KVCache(kv.k[0], kv.v[0])
         x = x + out
         h = layers.apply_norm(lp["norm_x"], cfg, x)
         x = x + attention.cross_attend(lp["cross_attn"], cfg, h, ckv)

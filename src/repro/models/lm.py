@@ -11,6 +11,7 @@ API (all pure):
   init_state(cfg, batch, max_len) -> LMState
   prefill(params, cfg, tokens, state, embeds=None) -> (last_logits [B,V], LMState)
   decode(params, cfg, tokens [B,1], state) -> (logits [B,V], LMState)
+    (the state's caches updated in place; see ``decode``)
 
 ``prefill`` is *suffix* prefill whenever ``state.pos > 0``: positions
 ``[0, state.pos)`` of the caches are treated as reused context state (the
@@ -270,24 +271,33 @@ def prefill_fused(
 def decode(
     params: Params, cfg: ArchConfig, tokens: jax.Array, state: LMState
 ) -> Tuple[jax.Array, LMState]:
-    kinds, _ = _layout(cfg)
+    """One token per sequence.  The stacked caches ride the layer loop's
+    carry and the layer params are its ``xs``: each layer writes its B new
+    K/V rows into the carried ``[n_periods, B, L, KV, hd]`` buffers at
+    ``(period, b, pos[b])`` and the attention reads its layer where it lies,
+    so under jit the buffers are updated in place (no per-layer slice or
+    write-back; donate the state to reuse its buffers across steps).  SSM
+    state is indexed out of the carry and written back per layer."""
+    kinds, n_periods = _layout(cfg)
     x = _embed_inputs(params, cfg, tokens, None)
     pos = state.pos
 
-    def period_fn(x, per):
-        layer_params, caches = per
-        new_caches = []
-        for i, kind in enumerate(kinds):
-            x, c = blocks.decode(layer_params[i], cfg, kind, x, caches[i], pos)
-            new_caches.append(c)
-        return x, tuple(new_caches)
+    def period_fn(carry, per):
+        x, caches = carry
+        layer_params, i = per
+        caches = list(caches)
+        for j, kind in enumerate(kinds):
+            x, caches[j] = blocks.decode(layer_params[j], cfg, kind, x, caches[j], i, pos)
+        return (x, tuple(caches)), None
 
-    x, new_caches = jax.lax.scan(
-        period_fn, x, (tuple(params["layers"]), state.caches), unroll=cfg.scan_unroll
+    (x, caches), _ = jax.lax.scan(
+        period_fn, (x, state.caches),
+        (tuple(params["layers"]), jnp.arange(n_periods, dtype=jnp.int32)),
+        unroll=cfg.scan_unroll,
     )
     x = layers.apply_norm(params["final_norm"], cfg, x)
     logits = layers.lm_logits(params["embed"], cfg, x)[:, 0]
-    return logits, LMState(pos=pos + 1, caches=new_caches)
+    return logits, LMState(pos=pos + 1, caches=caches)
 
 
 # --------------------------------------------------------------------------- #

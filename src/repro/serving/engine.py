@@ -357,7 +357,9 @@ class ServingEngine:
         self._next_migration_s = self.ec.migration_interval_s
 
         self._jit_prefill = jax.jit(self._prefill_impl)
-        self._jit_decode = jax.jit(self._decode_impl)
+        # the dense state is donated: the engine replaces ``self._state`` with
+        # the step's result, so the decode program updates the cache in place
+        self._jit_decode = jax.jit(self._decode_impl, donate_argnums=(2,))
         self._jit_packed = (
             jax.jit(self._packed_prefill_impl)
             if self.api.prefill_packed is not None

@@ -85,14 +85,16 @@ def test_flash_noncausal():
 )
 def test_decode_matches_ref(B, L, H, KV, hd):
     q = randn(B, 1, H, hd)
-    k, v = randn(B, L, KV, hd), randn(B, L, KV, hd)
+    # a three-layer stack: the kernel reads layer 1 where it lies
+    k, v = randn(3, B, L, KV, hd), randn(3, B, L, KV, hd)
     pos = jnp.asarray(RNG.integers(L // 2, L, (B, 1)), jnp.int32)
     idx = jnp.arange(L)[None]
     kv_pos = jnp.where(idx <= pos, idx, -1)
     out = decode_attention(
-        q, k, v, q_pos=pos, kv_pos=kv_pos, interpret=True, block_kv=8
+        q, k, v, layer=jnp.int32(1), q_pos=pos, kv_pos=kv_pos, interpret=True,
+        block_kv=8,
     )
-    want = ref.attention_ref(q, k, v, q_pos=pos, kv_pos=kv_pos, causal=True)
+    want = ref.attention_ref(q, k[1], v[1], q_pos=pos, kv_pos=kv_pos, causal=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -100,16 +102,18 @@ def test_decode_ring_buffer_positions():
     """SWA ring semantics: slots hold arbitrary absolute positions."""
     B, W, H, KV, hd = 2, 16, 4, 2, 8
     q = randn(B, 1, H, hd)
-    k, v = randn(B, W, KV, hd), randn(B, W, KV, hd)
+    k, v = randn(2, B, W, KV, hd), randn(2, B, W, KV, hd)
     from repro.models.attention import _ring_positions
 
     length = jnp.asarray([20, 9])
     kv_pos = _ring_positions(length, W, B)
     pos = (length - 1)[:, None]
     out = decode_attention(
-        q, k, v, q_pos=pos, kv_pos=kv_pos, window=W, interpret=True, block_kv=8
+        q, k, v, layer=jnp.int32(1), q_pos=pos, kv_pos=kv_pos, window=W,
+        interpret=True, block_kv=8,
     )
-    want = ref.attention_ref(q, k, v, q_pos=pos, kv_pos=kv_pos, causal=True, window=W)
+    want = ref.attention_ref(
+        q, k[1], v[1], q_pos=pos, kv_pos=kv_pos, causal=True, window=W)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
@@ -250,7 +254,7 @@ def _dispatch_calls(ops, hd, S=128):
             kv_seg=jnp.zeros_like(pos)),
         "fused_prefill": lambda: ops.fused_prefill(q, k, v, q_pos=pos, kv_pos=pos),
         "decode_attention": lambda: ops.decode_attention(
-            q1, k, v, q_pos=last, kv_pos=pos),
+            q1, k[None], v[None], layer=0, q_pos=last, kv_pos=pos),
         "paged_decode": lambda: ops.paged_decode(
             q1, pool, pool, block_table=tbl, q_pos=last, block=blk),
         "chunked_prefill": lambda: ops.chunked_prefill(

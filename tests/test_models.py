@@ -90,7 +90,8 @@ def test_attention_prefill_ring_matches_full_attention():
 
     # and decode continues correctly off the ring state
     x1 = jnp.asarray(RNG.standard_normal((B, 1, cfg.d_model)) * 0.2, jnp.float32)
-    dec, _ = attention.decode(p, cfg, x1, cache, jnp.full((B,), S, jnp.int32))
+    stacked = attention.KVCache(cache.k[None], cache.v[None])  # one layer
+    dec, _ = attention.decode(p, cfg, x1, stacked, 0, jnp.full((B,), S, jnp.int32))
     full2 = attention.forward(p, cfg, jnp.concatenate([x, x1], 1))
     np.testing.assert_allclose(np.asarray(dec[:, 0]), np.asarray(full2[:, -1]), atol=1e-4)
 
@@ -131,3 +132,103 @@ def test_suffix_prefill_equals_full_prefill(arch):
     d1, _ = api.decode(params, cfg, nxt, full_state)
     d2, _ = api.decode(params, cfg, nxt, st2)
     np.testing.assert_allclose(np.asarray(d1), np.asarray(d2), atol=3e-4)
+
+
+def _scan_decode(params, cfg, tokens, state):
+    """The previous dense decode: a scan with (params, caches) as its ``xs``,
+    each layer's cache sliced out of the stack and written back as ``ys``."""
+    from repro.models import blocks, lm
+
+    kinds, _ = lm._layout(cfg)
+    x = layers.embed_tokens(params["embed"], cfg, tokens)
+
+    def period_fn(x, per):
+        layer_params, caches = per
+        new = []
+        for i, kind in enumerate(kinds):
+            one = jax.tree_util.tree_map(lambda a: a[None], caches[i])
+            x, c = blocks.decode(layer_params[i], cfg, kind, x, one, 0, state.pos)
+            new.append(jax.tree_util.tree_map(lambda a: a[0], c))
+        return x, tuple(new)
+
+    x, caches = jax.lax.scan(period_fn, x, (tuple(params["layers"]), state.caches))
+    x = layers.apply_norm(params["final_norm"], cfg, x)
+    logits = layers.lm_logits(params["embed"], cfg, x)[:, 0]
+    return logits, lm.LMState(pos=state.pos + 1, caches=caches)
+
+
+@pytest.mark.parametrize("arch,mode", [
+    ("qwen2-1.5b", "ref"),
+    ("qwen2-1.5b", "pallas_interpret"),
+    ("mixtral-8x22b", "ref"),  # ring-buffer sliding window
+    ("mixtral-8x22b", "pallas_interpret"),
+    ("jamba-1.5-large-398b", "ref"),  # hybrid attention + SSM period
+    ("jamba-1.5-large-398b", "pallas_interpret"),
+    ("mamba2-1.3b", "ref"),  # no attention cache: the carry holds SSM state
+])
+def test_in_place_decode_matches_scan_and_recompute(arch, mode):
+    """Dense decode with the stacked caches in the layer loop's carry (the
+    state donated, as the engine runs it) equals the previous scan over
+    per-layer slices, step by step, with a slot frozen while inactive and a
+    context landed in another slot mid-way by ``insert_slot``; each active
+    slot's logits equal a full recompute of its tokens (``forward``, whose
+    attention is ``ref.attention_ref`` here)."""
+    from repro.kernels import ops
+    from repro.kvcache import paged
+
+    over = {"head_dim": 128} if mode == "pallas_interpret" else {}
+    cfg = reduced_config(get_config(arch), **over)
+    api = registry.get_model(cfg)
+    params = api.init(jax.random.PRNGKey(11), cfg)
+    B, S, max_len, steps = 3, 20, 32, 4
+    rng = np.random.default_rng(3)
+    hist = [list(r) for r in rng.integers(0, cfg.vocab, (B, S))]
+    landed = list(rng.integers(0, cfg.vocab, 9))
+
+    def impl(p, t, s, a):
+        logits, new = api.decode(p, cfg, t, s)
+        return logits, new._replace(pos=jnp.where(a, new.pos, s.pos))
+
+    def old(p, t, s, a):
+        logits, new = _scan_decode(p, cfg, t, s)
+        return logits, new._replace(pos=jnp.where(a, new.pos, s.pos))
+
+    ops.set_kernel_mode(mode)
+    try:
+        new_step = jax.jit(impl, donate_argnums=(2,))
+        old_step = jax.jit(old)
+        prefill = jax.jit(lambda p, t, s: api.prefill(p, cfg, t, s))
+        first, state = prefill(params, jnp.asarray(hist, jnp.int32),
+                               api.init_state(cfg, B, max_len))
+        pending = [int(t) for t in np.asarray(jnp.argmax(first, -1))]
+        one_first, one = prefill(params, jnp.asarray([landed], jnp.int32),
+                                 api.init_state(cfg, 1, max_len))
+        art = paged.extract_slot(cfg, one, 0, len(landed))
+        state_old = jax.tree_util.tree_map(jnp.copy, state)
+        active = np.array([True, True, False])  # slot 2 frozen
+        for step in range(steps):
+            if step == 2:  # land a stored context in slot 1 between steps
+                state = paged.insert_slot(cfg, state, 1, art)
+                state_old = paged.insert_slot(cfg, state_old, 1, art)
+                hist[1] = list(landed)
+                pending[1] = int(jnp.argmax(one_first[0]))
+            toks = jnp.asarray([[t] for t in pending], jnp.int32)
+            a = jnp.asarray(active)
+            got, state = new_step(params, toks, state, a)
+            want, state_old = old_step(params, toks, state_old, a)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
+            nxt = np.asarray(jnp.argmax(got, -1))
+            for b in np.flatnonzero(active):
+                hist[b].append(pending[b])
+                pending[b] = int(nxt[b])
+        for b in np.flatnonzero(active):  # the last step against recompute
+            full, _ = jax.jit(lambda p, t: api.forward(p, cfg, t))(
+                params, jnp.asarray([hist[b]], jnp.int32))
+            np.testing.assert_allclose(
+                np.asarray(got[b]), np.asarray(full[0, -1]), atol=2e-4)
+        assert np.asarray(state.pos).tolist() == [S + steps, len(landed) + steps - 2, S]
+        jax.tree_util.tree_map(
+            lambda x, y: np.testing.assert_allclose(np.asarray(x), np.asarray(y), atol=1e-5),
+            state, state_old)
+    finally:
+        ops.set_kernel_mode(None)

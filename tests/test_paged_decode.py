@@ -115,7 +115,8 @@ def test_paged_pallas_matches_dense_decode_kernel():
 
     c = _pool_case([100, 256, 17], KV=2, hd=16, block=128, max_len=256, seed=5)
     dense = dk.decode_attention(
-        jnp.asarray(c["q"]), jnp.asarray(c["dense_k"]), jnp.asarray(c["dense_v"]),
+        jnp.asarray(c["q"]), jnp.asarray(c["dense_k"])[None],
+        jnp.asarray(c["dense_v"])[None], layer=0,
         q_pos=jnp.asarray(c["q_pos"]), kv_pos=jnp.asarray(c["kv_pos"]),
         interpret=True,
     )

@@ -440,3 +440,44 @@ def test_min_cache_tokens_gates_write_back():
     )
     assert len(eng_eq.store.entries) == len(eng_def.store.entries)
     assert tok_eq == tok_def
+
+
+def test_dense_decode_donates_state_and_admits_between_steps():
+    """Dense decode donates the engine's state to each step (the caches are
+    updated in place), and requests admitted between decode steps still get
+    the tokens of a full greedy recompute of their own sequence."""
+    cfg, params = _setup("llama-7b")
+    api = registry.get_model(cfg)
+    reqs = _requests(cfg, n=5, new=6)
+    for i, r in enumerate(reqs):  # arrivals spread over the decode steps
+        r["arrival_s"] = 0.0 if i < 2 else 1e9
+    eng = ServingEngine(
+        cfg, params,
+        engine_cfg=EngineConfig(max_slots=3, max_len=128, chunk_tokens=16),
+        planner=AlwaysReusePlanner(),
+    )
+    pending = list(reqs)
+    for r in pending[:2]:
+        eng.submit(Request(**r))
+    pending = pending[2:]
+    donated = 0
+    while not eng.idle or pending:
+        if pending and any(s.active for s in eng.slots):
+            # admit one more between decode steps, with a slot still decoding
+            eng.submit(Request(**dict(pending.pop(0), arrival_s=0.0)))
+        before = eng._state
+        events = eng.step()
+        if any(isinstance(e, ev.TokenEmitted) for e in events) and eng._state is not before:
+            donated += all(leaf.is_deleted() for leaf in jax.tree_util.tree_leaves(before))
+    assert donated > 0
+
+    forward = jax.jit(lambda p, t: api.forward(p, cfg, t)[0])
+    for rec in eng.records:
+        r = reqs[rec.req_id]
+        seq = list(r["context_tokens"]) + list(r["prompt_tokens"])
+        want = []
+        for _ in range(r["max_new_tokens"]):
+            padded = jnp.asarray([seq + [0] * (128 - len(seq))], jnp.int32)
+            want.append(int(jnp.argmax(forward(params, padded)[0, len(seq) - 1])))
+            seq.append(want[-1])
+        assert rec.tokens == want, rec.req_id
