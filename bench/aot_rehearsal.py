@@ -2,13 +2,15 @@
 """Ahead-of-time memory rehearsal of each configuration's largest programs,
 compiled for a described TPU v5e chip (no chip needed, nothing runs).
 
-    JAX_PLATFORMS=cpu python3 bench/aot_rehearsal.py [config ...]
+    JAX_PLATFORMS=cpu python3 bench/aot_rehearsal.py [cell ...]
 
-For each configuration file it compiles, at the engine settings the cells
-use, the dense decode step, the largest packed prefill of the set-up fill
-(one fresh document of the longest length) and the largest packed
-admission the window can form (``admit_batch`` requests of the longest
-document and question), with the Pallas kernels, and prints each
+For each cell of ``BENCHMARK.json`` (of a dense model) it compiles, at
+the engine settings of its configuration, the dense decode step, the
+largest packed prefill of the set-up fill (one fresh document of the
+longest length) and the largest packed admission the window can form
+(``admit_batch`` requests of the longest document and question: behind
+the stored document where the traffic asks each document more than once,
+else the whole document fresh), with the Pallas kernels, and prints each
 program's ``memory_analysis()`` as one JSON line.  The weights and the
 dense decode state are among a program's arguments; the engine holds both
 at once, so the largest program's total is about a run's peak.
@@ -53,7 +55,10 @@ def programs(conf: dict, spec: dict):
     doc = int(spec["documents"]["length"]["max"])
     q = int(spec["question"]["length"]["max"])
     fill = (1, doc + int(spec["fill_prompt_tokens"]), 0)
-    window = (k_max, q, doc)
+    if int(spec["documents"]["asks_per_document"]) > 1:
+        window = (k_max, q, doc)
+    else:
+        window = (k_max, doc + q, 0)
     for name, (k, n_new, matched) in (("packed_fill", fill), ("packed_window", window)):
         layout = paged.pack_layout(list(range(k)), [matched] * k, [n_new] * k)
         # a dense stack is one block kind, stacked over its layers
@@ -81,21 +86,23 @@ def main(argv=None) -> int:
     from repro.kernels import ops
 
     jax.config.update("jax_enable_compilation_cache", False)
-    names = (argv if argv is not None else sys.argv[1:]) or [
-        p.stem for p in sorted(harness.CONFIGS.glob("*.json"))]
+    bench = json.loads((harness.REPO / "BENCHMARK.json").read_text())
+    cells = {w["name"]: w for w in bench["workloads"]}
+    names = (argv if argv is not None else sys.argv[1:]) or list(cells)
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     one = SingleDeviceSharding(topo.devices[0])
     ops.set_kernel_mode("pallas")
     for name in names:
-        conf = harness.load_config(name)
-        spec = traffic_mod.load("docqa_reuse", name)
+        w = cells[name]
+        conf = harness.load_config(w["config"])
+        spec = traffic_mod.load(w["traffic"], w["config"])
         for prog, fn, shapes in programs(conf, spec):
             placed = jax.tree_util.tree_map(
                 lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one), shapes)
             compiled = jax.jit(fn).lower(*placed).compile()
             m = compiled.memory_analysis()
             print(json.dumps({
-                "config": name, "program": prog,
+                "cell": name, "program": prog,
                 "argument_bytes": m.argument_size_in_bytes,
                 "output_bytes": m.output_size_in_bytes,
                 "temp_bytes": m.temp_size_in_bytes,

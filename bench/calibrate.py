@@ -39,16 +39,16 @@ def main(argv=None) -> int:
     from bench import correct, runner
     from bench import traffic as traffic_mod
     from bench import weights as bench_weights
-    from bench.flops import Dims
 
     conf = harness.load_config(w["config"])
-    dims = Dims.from_config(conf["config"])
+    model = harness.model_of(conf)
+    dims = model.sizes(conf["config"])
     spec = traffic_mod.load(w["traffic"], w["config"])
     seeds = [int(s) for s in args.seeds.split(",")]
     n_control = len(seeds) if args.control is None else args.control
     for i, seed in enumerate(seeds):
         t0 = time.perf_counter()
-        wts = bench_weights.make(dims, seed, devs[0])
+        wts = bench_weights.make(model, dims, seed, devs[0])
         engine, _ = harness.build(conf, seed, devs[0], weights=wts)
         tr = traffic_mod.generate(spec, seed, args.seconds, dims.vocab)
         harness.warm(engine, tr, harness.fill(engine, tr))
@@ -57,7 +57,7 @@ def main(argv=None) -> int:
         del engine
         harness.free_device(keep=wts)
         t1 = time.perf_counter()
-        read = correct.readings(wts, dims, correct.sample(finished, seed),
+        read = correct.readings(model, wts, dims, correct.sample(finished, seed),
                                 control=i < n_control)
         print(json.dumps({"line": "calibrate", "seed": seed, **read,
                           "failed": runner.phase_counts(window)["failed"],

@@ -9,10 +9,11 @@ token lies below the reference's best logit at the same position.  The
 number compared is the widest such gap (``max_logit_gap``); greedy serving
 of a sound program keeps it at the size of bf16 rounding.
 
-The negative control puts the reference itself in the program's place, at
-the nearest precision below bf16 (float8 e4m3, ``reference.FP8``): at each
-position of the same sequences it reads the gap of the token the control
-puts first.
+The reference is the configuration's model module's ``logits``
+(``bench/models/<m>.py``).  The negative control puts the reference itself
+in the program's place, at the nearest precision below bf16 (float8 e4m3,
+``reference.FP8``): at each position of the same sequences it reads the
+gap of the token the control puts first.
 
 Beside them, every check reads the planted fault of a token altered where
 it is produced, with the reference in the program's place: a decode step
@@ -32,7 +33,6 @@ from typing import Dict, List, Sequence
 import numpy as np
 
 from bench import reference
-from bench.flops import Dims
 from bench.traffic import seed_rng
 
 MIN_SERVED = 320
@@ -82,18 +82,19 @@ def control_gaps(ref_logits: np.ndarray, control_logits: np.ndarray) -> np.ndarr
     return served_gaps(ref_logits, control_logits.argmax(axis=1))
 
 
-def readings(weights: dict, d: Dims, picked: Sequence[Served], control: bool = False) -> Dict:
+def readings(model, weights: dict, d, picked: Sequence[Served], control: bool = False) -> Dict:
     """The program's widest gap over the sample, and with ``control`` the
-    control's at the same positions."""
+    control's at the same positions; ``model`` is the configuration's model
+    module, whose ``logits`` is the plain reference, at sizes ``d``."""
     prog, ctrl, fault = [], [], []
     for s in picked:
         seq = s.prompt + s.tokens[:-1]
         rows = reference.served_rows(len(s.prompt), len(s.tokens))
-        ref = reference.logits(weights, d, seq, rows)
+        ref = model.logits(weights, d, seq, rows)
         prog.append(served_gaps(ref, s.tokens))
         fault.append(runner_up_gaps(ref[1:]))
         if control:
-            low = reference.logits(weights, d, seq, rows, fmt=reference.FP8)
+            low = model.logits(weights, d, seq, rows, fmt=reference.FP8)
             ctrl.append(control_gaps(ref, low))
     out = {"max_logit_gap": float(np.concatenate(prog).max()),
            "runner_up_max_logit_gap": float(np.concatenate(fault).max(initial=0.0)),

@@ -5,11 +5,16 @@ drive them:
 
 1. ``build``: the serving engine through the launcher's pieces
    (``repro.launch.serve.setup``), on one device, with the configuration
-   file's engine settings and the weights of ``bench/weights.py``.
-2. ``fill``: every document of the pool admitted once, fresh, through the
-   engine's own admission and write-back path (the paper's precompute).
+   file's engine settings and the weights of ``bench/weights.py``.  What
+   is particular to the architecture comes from the configuration's model
+   module, ``bench/models/<model>.py`` (``model``).
+2. ``fill``: every document the traffic asks more than once admitted once,
+   fresh, through the engine's own admission and write-back path (the
+   paper's precompute); documents asked once are left unseen.
 3. ``warm``: every admission shape that any arrival order of the run's
-   requests can form, and the decode step, run once before the window.
+   requests can form, through the admission program the engine uses
+   (packed, or one request at a time), and the decode step, run once
+   before the window.
 4. ``serve``: the open loop.  It starts with the traffic's lead-in, so
    that the window opens on a loaded engine; only requests that fall due
    inside the window are attempted ones.  A request is submitted when it
@@ -26,6 +31,7 @@ import contextlib
 import dataclasses
 import functools
 import gc
+import importlib.util
 import itertools
 import json
 import sys
@@ -38,6 +44,7 @@ REPO = Path(__file__).resolve().parents[1]
 BENCH = REPO / "bench"
 SRC = REPO / "src"
 CONFIGS = BENCH / "configs"
+MODELS = BENCH / "models"
 
 DRAIN_S = 60.0
 RID0 = 1_000_000  # request id of the window's first request (the lead-in's lie just below)
@@ -58,25 +65,35 @@ def load_config(name: str, directory: Path = CONFIGS) -> dict:
 # --------------------------------------------------------------------------- #
 # 1. build
 # --------------------------------------------------------------------------- #
-def program_config(conf: dict):
+@functools.lru_cache(maxsize=None)
+def model(name: str, directory: Path = MODELS):
+    """The model module ``<directory>/<name>.py``, loaded once per process:
+    the architecture's sizes, program fields, weight tree, plain reference
+    and counts (``bench/models/dense_gqa.py`` says what each is)."""
+    path = directory / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no model module {path}")
+    spec = importlib.util.spec_from_file_location(f"bench_model_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # its dataclasses look their module up there
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def model_of(conf: dict, directory: Path = MODELS):
+    """The model module the configuration file names (``"model"``)."""
+    return model(conf["model"], directory)
+
+
+def program_config(conf: dict, models_dir: Path = MODELS):
     """The program's ArchConfig for this configuration file, checked against
-    the file's own sizes, so that a drift of the program's configs shows."""
+    the fields its model module derives from the file's sizes, so that a
+    drift of the program's configs shows."""
     from repro.configs import get_config
 
     p = conf["program"]
     cfg = dataclasses.replace(get_config(p["arch"]), **p.get("replace", {}))
-    c = conf["config"]
-    want = {
-        "d_model": c["hidden_size"], "n_layers": c["num_hidden_layers"],
-        "n_heads": c["num_attention_heads"], "n_kv_heads": c["num_key_value_heads"],
-        "resolved_head_dim": c["head_dim"], "d_ff": c["intermediate_size"],
-        "vocab": c["vocab_size"], "padded_vocab": c["vocab_size"],
-        "tie_embeddings": c["tie_word_embeddings"], "qkv_bias": c["attention_bias"],
-        "rope_theta": c["rope_theta"], "norm_eps": c["rms_norm_eps"],
-        "dtype": c["torch_dtype"], "param_dtype": c["torch_dtype"],
-        "family": "dense", "mlp_type": "swiglu", "norm_type": "rmsnorm",
-        "sliding_window": None,
-    }
+    want = model_of(conf, models_dir).program_fields(conf["config"])
     got = {k: getattr(cfg, k) for k in want}
     if got != want:
         bad = {k: (got[k], want[k]) for k in want if got[k] != want[k]}
@@ -94,14 +111,14 @@ def check_tree(cfg, weights) -> None:
     want = jax.eval_shape(lambda k: api.init(k, cfg), jax.ShapeDtypeStruct((2,), jnp.uint32))
     sig = lambda t: jax.tree_util.tree_map(lambda a: (tuple(a.shape), str(a.dtype)), t)
     if sig(want) != sig(weights):
-        raise ValueError("the program's parameter tree differs from bench/weights.py")
+        raise ValueError("the program's parameter tree differs from the model module's")
 
 
-def build(conf: dict, seed: int, device, weights=None):
-    """(engine, dims): the engine on ``device``, weights from ``seed``."""
+def build(conf: dict, seed: int, device, weights=None, models_dir: Path = MODELS):
+    """(engine, dims): the engine on ``device``, weights from ``seed``;
+    ``dims`` are the sizes of the configuration's model module."""
     import jax
     from bench import weights as bench_weights
-    from bench.flops import Dims
     from repro.launch import serve as launch
     from repro.serving import ServingEngine
 
@@ -110,11 +127,12 @@ def build(conf: dict, seed: int, device, weights=None):
             "--platform", e["platform"], "--slots", str(e["slots"]),
             "--policy", e["policy"]]
     s = launch.setup(launch.parse_args(argv), **e.get("overrides", {}))
-    cfg = program_config(conf)
+    cfg = program_config(conf, models_dir)
     s = dataclasses.replace(s, cfg=cfg)
-    dims = Dims.from_config(conf["config"])
+    mod = model_of(conf, models_dir)
+    dims = mod.sizes(conf["config"])
     if weights is None:
-        weights = bench_weights.make(dims, seed, device)
+        weights = bench_weights.make(mod, dims, seed, device)
     check_tree(cfg, weights)
     engine = ServingEngine(
         cfg, weights, engine_cfg=s.engine_cfg, planner=s.planner_factory(),
@@ -134,40 +152,82 @@ def run_until_idle(engine) -> List:
     return events
 
 
+def asks(traffic) -> List[int]:
+    """Per document, how many of the run's requests ask it."""
+    n = [0] * len(traffic.docs)
+    for r in traffic.requests:
+        n[r.doc] += 1
+    return n
+
+
 def fill(engine, traffic) -> List[int]:
-    """Admit every document once, fresh, one at a time; the engine's planner
-    writes each back.  The first asks for two tokens, so that the decode
-    step runs too.  Returns, per document, the tokens the store now matches
-    (its whole chunks: the store hashes whole chunks of tokens)."""
+    """Admit once, fresh, one at a time, every document the traffic asks
+    more than once; the engine's planner writes each back.  A document
+    asked once is left unseen: its request pays the whole prefill.  The
+    first document admitted asks for two tokens, so that the decode step
+    runs too.  Returns, per document, the tokens the store now matches (its
+    whole chunks: the store hashes whole chunks of tokens; 0 where the
+    document was left unseen)."""
     from repro.serving.request import Request
 
-    for i, (doc, prompt) in enumerate(zip(traffic.docs, traffic.fill_prompts)):
+    stored = [i for i, n in enumerate(asks(traffic)) if n > 1]
+    for i in stored:
         engine.submit(Request(
-            req_id=i, context_tokens=doc, prompt_tokens=prompt,
-            max_new_tokens=2 if i == 0 else 1, arrival_s=engine.clock.now,
+            req_id=i, context_tokens=traffic.docs[i], prompt_tokens=traffic.fill_prompts[i],
+            max_new_tokens=2 if i == stored[0] else 1, arrival_s=engine.clock.now,
             expected_reuses=traffic.expected_reuses,
         ))
         run_until_idle(engine)
-    matched = []
-    for i, doc in enumerate(traffic.docs):
+    matched = [0] * len(traffic.docs)
+    for i in stored:
+        doc = traffic.docs[i]
         match, entry = engine.store.lookup(doc)
         if entry is None or match.matched_tokens <= 0:
             raise RuntimeError(f"document {i} ({len(doc)} tokens) was not stored by the fill")
-        matched.append(match.matched_tokens)
+        matched[i] = match.matched_tokens
     return matched
 
 
 # --------------------------------------------------------------------------- #
 # 3. warm
 # --------------------------------------------------------------------------- #
+@dataclasses.dataclass(frozen=True)
+class Program:
+    """A jitted program of the engine as the trace names it (``jit_`` and
+    the function's name), and whether each step of its kind runs it once
+    (``per_step``) or as many times as the step launches it."""
+
+    name: str
+    per_step: bool
+
+
+def programs(engine) -> Dict[str, Program]:
+    """The programs of the engine's admission and decode steps: the packed
+    prefill where the engine packs its admissions, else its per-request
+    prefill (one launch, or two where the context is written back between
+    them)."""
+    def name(fn) -> str:
+        return f"jit_{fn.__name__}"
+
+    decode = Program(name(engine._jit_decode), per_step=True)
+    if getattr(engine, "_packable", False) and getattr(engine, "_jit_packed", None) is not None:
+        return {"admit": Program(name(engine._jit_packed), per_step=True), "decode": decode}
+    return {"admit": Program(name(engine._jit_prefill), per_step=False), "decode": decode}
+
+
+def request_sizes(traffic, matched: List[int]) -> List[tuple]:
+    """The distinct (matched, n_new, context) of the run's requests."""
+    return sorted({(matched[r.doc], len(traffic.docs[r.doc]) - matched[r.doc] + len(r.question),
+                    len(traffic.docs[r.doc])) for r in traffic.requests})
+
+
 def admission_sets(engine, traffic, matched: List[int]) -> List[List]:
     """Every batch that any arrival order can admit: each multiset of up to
     ``admit_batch`` of the run's request sizes (admission takes the queue's
     head, and the seed draws the order).  Each is (matched, n_new) per
     request, ``matched[d]`` being what the store holds of document d."""
     k_max = min(engine.ec.admit_batch or engine.ec.max_slots, engine.ec.max_slots)
-    sizes = sorted({(matched[r.doc], len(traffic.docs[r.doc]) - matched[r.doc] + len(r.question))
-                    for r in traffic.requests})
+    sizes = sorted({(m, n) for m, n, _ in request_sizes(traffic, matched)})
     return [list(c) for k in range(1, k_max + 1)
             for c in itertools.combinations_with_replacement(sizes, k)]
 
@@ -175,20 +235,49 @@ def admission_sets(engine, traffic, matched: List[int]) -> List[List]:
 def warm(engine, traffic, matched: List[int],
          log: Callable[[str], None] = print) -> Dict[str, int]:
     """Run, before the window, the device work of every admission shape any
-    arrival order can form: the packed launch of each (q, kv) bucket pair,
-    and the per-request slicing and slot insertion at each length.  The
-    program compiles these per shape; this replays the engine's own calls
-    (``_packed_launch``, ``paged.packed_to_artifact``, ``paged.insert_slot``)
-    on zero inputs and drops what they return.  An engine without those
-    calls is an error: the window would compile them."""
+    arrival order can form, through the admission program the engine uses
+    (``programs``), then one decode step.  The program compiles these per
+    shape; this replays the engine's own calls on zero inputs and drops
+    what they return.  An engine without those calls is an error: the
+    window would compile them."""
+    import jax
+    import jax.numpy as jnp
+
+    need = ("_jit_decode", "_state")
+    if not all(hasattr(engine, a) for a in need) or engine._state is None:
+        raise RuntimeError(f"warm-up: the engine has no {need}; its admission shapes cannot be warmed")
+    with jax.default_device(engine.device):
+        if programs(engine)["admit"].per_step:
+            out = _warm_packed(engine, traffic, matched)
+        else:
+            out = _warm_single(engine, traffic, matched)
+        # one decode step with every slot inactive: positions stay, and the
+        # rows it writes lie past every slot's valid length
+        n = engine.ec.max_slots
+        logits, engine._state = engine._jit_decode(
+            engine.params, jnp.zeros((n, 1), jnp.int32), engine._state, jnp.zeros((n,), bool))
+        jnp.argmax(logits, axis=-1).block_until_ready()
+    return out
+
+
+def _warm_packed(engine, traffic, matched: List[int]) -> Dict[str, int]:
+    """The packed launch of each (q, kv) bucket pair, and the per-request
+    slicing and slot insertion at each length: ``_packed_launch``,
+    ``paged.packed_to_artifact`` (also at the context's length where a fresh
+    context may be written back) and ``paged.insert_slot``."""
     import jax
     import jax.numpy as jnp
     from repro.kvcache import paged
 
-    need = ("_packed_launch", "_state")
-    if not all(hasattr(engine, a) for a in need) or engine._state is None:
-        raise RuntimeError(f"warm-up: the engine has no {need}; its admission shapes cannot be warmed")
+    if not hasattr(engine, "_packed_launch"):
+        raise RuntimeError("warm-up: the engine has no _packed_launch; its admission shapes "
+                           "cannot be warmed")
     ec, cfg = engine.ec, engine.cfg
+    # a fresh segment of n_new tokens may write back its first `context` rows
+    written: Dict[int, set] = {}
+    for m, n_new, ctx in request_sizes(traffic, matched):
+        if m == 0:
+            written.setdefault(n_new, set()).add(ctx)
     by_pair: Dict[tuple, list] = {}
     for run in admission_sets(engine, traffic, matched):
         layout = paged.pack_layout(
@@ -196,26 +285,72 @@ def warm(engine, traffic, matched: List[int],
             align=ec.pack_align, bucket_min=ec.pack_bucket_min)
         by_pair.setdefault((layout.q_len, layout.kv_len), []).append(layout)
     sliced, inserted = set(), set()
-    with jax.default_device(engine.device):
-        for (q_len, kv_len), layouts in sorted(by_pair.items()):
-            first = layouts[0]
-            logits, caches = engine._packed_launch(
-                first, [[0] * s.n_new for s in first.segments], [None] * len(first.segments))
-            jnp.argmax(logits[0]).block_until_ready()
-            for layout in layouts:
-                for seg in layout.segments:
-                    if (kv_len, seg.n_total) in sliced:
+    for (q_len, kv_len), layouts in sorted(by_pair.items()):
+        first = layouts[0]
+        logits, caches = engine._packed_launch(
+            first, [[0] * s.n_new for s in first.segments], [None] * len(first.segments))
+        jnp.argmax(logits[0]).block_until_ready()
+        for layout in layouts:
+            for seg in layout.segments:
+                lengths = [seg.n_total] + sorted(written.get(seg.n_new, ()) if seg.matched == 0
+                                                 else ())
+                for n in lengths:
+                    if (kv_len, n) in sliced:
                         continue
-                    sliced.add((kv_len, seg.n_total))
-                    art = paged.packed_to_artifact(cfg, caches, seg, seg.n_total)
-                    if seg.n_total not in inserted:
-                        inserted.add(seg.n_total)
+                    sliced.add((kv_len, n))
+                    art = paged.packed_to_artifact(cfg, caches, seg, n)
+                    if n == seg.n_total and n not in inserted:
+                        inserted.add(n)
                         out = paged.insert_slot(cfg, engine._state, 0, art)
                         jax.block_until_ready(out)
                         del out
+                    else:
+                        jax.block_until_ready(art)
                     del art
-            del logits, caches
+        del logits, caches
     return {"pairs": len(by_pair), "lengths": len(inserted), "slices": len(sliced)}
+
+
+def _warm_single(engine, traffic, matched: List[int]) -> Dict[str, int]:
+    """Each request's launches on the per-request path (``_jit_prefill`` on
+    a batch-1 state, one program per token length): the context and
+    question in one launch; the context alone, its artifact
+    (``paged.extract_slot``) and then the question, where the context is
+    written back; where the store holds part of it, the stored rows
+    inserted (``paged.insert_slot``) and the rest prefilled.  Each with the
+    first token's argmax and the landing of the state in a slot, at each
+    request's total length."""
+    import jax
+    import jax.numpy as jnp
+    from repro.kvcache import paged
+
+    cfg, ec = engine.cfg, engine.ec
+    launched, extracted, inserted, landed = set(), set(), set(), set()
+
+    def prefill(n):
+        launched.add(n)
+        state = engine.api.init_state(cfg, 1, ec.max_len)
+        tokens = jnp.asarray([[0] * n], jnp.int32)  # as the engine makes them
+        logits, state = engine._jit_prefill(engine.params, tokens, state)
+        int(jnp.argmax(logits[0]))
+        return state
+
+    for m, n_new, ctx in request_sizes(traffic, matched):
+        total = m + n_new
+        for n in sorted({total - ctx, n_new} - launched):
+            prefill(n)
+        if total not in landed:
+            landed.add(total)
+            jax.block_until_ready(paged.insert_slot(cfg, engine._state, 0, prefill(total)))
+        if ctx not in extracted or (m and m not in inserted):
+            extracted.add(ctx)
+            art = paged.extract_slot(cfg, prefill(ctx), 0, ctx)
+            if m and m not in inserted:
+                inserted.add(m)
+                fresh = engine.api.init_state(cfg, 1, ec.max_len)
+                jax.block_until_ready(paged.insert_slot(cfg, fresh, 0, art, n_tokens=m))
+    return {"lengths": len(launched), "extracted": len(extracted), "inserted": len(inserted),
+            "landed": len(landed)}
 
 
 # --------------------------------------------------------------------------- #
@@ -268,6 +403,7 @@ class Window:
     queue_depth: List[tuple]  # (wall time, requests queued or in a slot)
     run_end: float  # when the drain ended
     carried: List[WinReq] = dataclasses.field(default_factory=list)  # the lead-in's
+    written_back: List[tuple] = dataclasses.field(default_factory=list)  # (wall time, bytes)
 
     @property
     def seconds(self) -> float:
@@ -306,6 +442,7 @@ def serve(engine, traffic, seconds: float, annotate: bool = False,
     steps: List[Step] = []
     late: List[float] = []
     depth: List[tuple] = []
+    written: List[tuple] = []
     pending = list(traffic.requests)
     nxt = 0
     lead_s = max(0.0, -min((r.due_s for r in pending), default=0.0))
@@ -358,14 +495,18 @@ def serve(engine, traffic, seconds: float, annotate: bool = False,
             events = engine.step()
         t1 = time.perf_counter()
         step = Step(t0=t0, t1=t1, kind="other", in_window=phase == "window")
+        single = []  # requests admitted one at a time (no BatchAdmitted)
         for e in events:
             r = reqs.get(e.req_id)
             if isinstance(e, ev.BatchAdmitted):
                 step.kind, step.batch = "admit", list(e.req_ids)
+            elif isinstance(e, ev.StoreWriteBack):
+                written.append((t1, e.nbytes))
             elif r is None:
                 continue
             elif isinstance(e, ev.RequestAdmitted):
                 r.admit_began, r.admitted = t0, t1
+                single.append(r.rid)
             elif isinstance(e, ev.KVLoaded):
                 r.matched = e.matched_tokens
             elif isinstance(e, ev.TokenEmitted):
@@ -376,12 +517,14 @@ def serve(engine, traffic, seconds: float, annotate: bool = False,
                     step.decoded = (step.decoded or []) + [(r.rid, e.index)]
             elif isinstance(e, ev.RequestFinished):
                 r.finished, r.finished_at = True, t1
+        if single and step.batch is None:
+            step.kind, step.batch = "admit", single
         steps.append(step)
         depth.append((t1, engine.load()))
     every = sorted(reqs.values(), key=lambda r: r.rid)
     return Window(start=start, end=end, reqs=[r for r in every if not r.lead], steps=steps,
                   generator_late=late, queue_depth=depth, run_end=time.perf_counter(),
-                  carried=[r for r in every if r.lead])
+                  carried=[r for r in every if r.lead], written_back=written)
 
 
 # --------------------------------------------------------------------------- #

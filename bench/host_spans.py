@@ -56,7 +56,7 @@ from typing import Dict, List, Optional, Sequence, Tuple  # noqa: E402
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
-from bench import harness, match, run  # noqa: E402
+from bench import harness, run  # noqa: E402
 from bench import trace as trace_mod  # noqa: E402
 
 PREFIXES = ("engine.", "store.")
@@ -154,13 +154,13 @@ def decode_host_ms(events: Sequence[HostEvent], red) -> Optional[float]:
                              for h in steps)
 
 
-def decode_split(events: Sequence[HostEvent], red) -> Optional[dict]:
+def decode_split(events: Sequence[HostEvent], red, program: str) -> Optional[dict]:
     """Medians over the window's decode steps, in ms: the ``engine.step``
     span, its host time the device does not cover (``decode_host_ms``) and
-    the decode program's device time; the first should be about the sum of
-    the other two."""
+    the device time of the decode program (``program``, as the trace names
+    it); the first should be about the sum of the other two."""
     steps = decode_steps(in_window(events, red.lo, red.hi))
-    runs = red.executions(match.DECODE_PROGRAM)
+    runs = red.executions(program)
     if not steps or not runs:
         return None
     return {"steps": len(steps),
@@ -290,13 +290,15 @@ def seconds_in(spans: Sequence, name: str) -> Optional[float]:
 class Phases:
     """Takes the recorder's spans at the set-up's boundaries while a run is
     in ``runner.run_cell``: ``fill`` and ``warm`` hold the spans of the
-    store fill and the warm-up; ``reduction`` the window's reduced trace."""
+    store fill and the warm-up; ``reduction`` the window's reduced trace;
+    ``programs`` the engine's programs (``harness.programs``)."""
 
     def __init__(self, host):
         self.host = host
         self.fill: List = []
         self.warm: List = []
         self.reduction = None
+        self.programs = None
         self._saved = []
 
     def _wrap(self, mod, name, before=None, after=None):
@@ -320,6 +322,7 @@ class Phases:
         self._wrap(harness, "warm", before=lambda: self.fill.extend(take()))
         self._wrap(harness, "serve", before=lambda: self.warm.extend(take()))
         self._wrap(trace_mod, "reduce_dir", after=lambda r: setattr(self, "reduction", r))
+        self._wrap(harness, "programs", after=lambda p: setattr(self, "programs", p))
         return self
 
     def __exit__(self, *exc) -> bool:
@@ -359,7 +362,8 @@ def main(argv=None) -> int:
     red = ph.reduction
     events = events_for(red)
     window = {} if red is None else {"window": span_table(events, red),
-                                     "decode": decode_split(events, red)}
+                                     "decode": decode_split(events, red,
+                                                            ph.programs["decode"].name)}
     runner.say("host_spans", dropped=host.dropped(), **window,
                setup={"fill": setup_table(ph.fill), "warm": setup_table(ph.warm)})
     if red is not None:

@@ -20,21 +20,25 @@ from typing import Callable, Dict, List, Optional
 
 from bench import correct, harness, stats
 from bench import traffic as traffic_mod
-from bench.flops import Dims, peaks
+from bench.flops import peaks
 
 METRICS = harness.BENCH / "metrics"
 
 
 @dataclasses.dataclass
 class RunView:
-    """What a metric reader reads: the window, the sizes, the chip's peaks,
-    the set-up time and, in a traced run, the trace's reduction."""
+    """What a metric reader reads: the window, the model module and its
+    sizes, the chip's peaks, the set-up time, the engine's admission and
+    decode programs (``harness.programs``) and, in a traced run, the
+    trace's reduction."""
 
     window: harness.Window
-    dims: Dims
+    dims: object  # the model module's sizes
     peak: Optional[dict]
     setup_s: float
     trace: Optional[object] = None  # bench.trace.Reduction
+    model: Optional[object] = None  # bench/models/<m>.py
+    programs: Optional[Dict[str, harness.Program]] = None
 
 
 def reader(name: str, directory: Path = METRICS) -> Callable[[RunView], Optional[float]]:
@@ -94,19 +98,16 @@ def served(window: harness.Window, traffic) -> List[correct.Served]:
 
 
 def roofline_bounds(view: RunView) -> dict:
-    """Per kernel, how many traced calls each roofline bound limits."""
+    """Per kernel of the model, how many traced calls each roofline bound
+    limits."""
     from bench import flops, match
 
     out = {}
-    for kernel, kind, program, work in (
-        ("packed_prefill", "admit", match.PACKED_PROGRAM,
-         lambda s: flops.packed_prefill_call(view.dims, match.segments(view, s))),
-        ("decode_attention", "decode", match.DECODE_PROGRAM,
-         lambda s: flops.decode_attention_call(view.dims, match.lives(view, s))),
-    ):
+    for kernel, k in view.model.KERNELS.items():
         counts = {"compute": 0, "memory": 0}
-        for step, _ in match.pairs(view, kind, program) or []:
-            counts[flops.least_time(*work(step), view.peak)[1]] += 1
+        for step, _ in match.pairs(view, k.step) or []:
+            work = k.call(view.dims, match.work(view, step))
+            counts[flops.least_time(*work, view.peak)[1]] += 1
         out[kernel] = counts
     return out
 
@@ -145,6 +146,13 @@ def latency(window: harness.Window) -> dict:
     return out
 
 
+def written_back(window: harness.Window) -> dict:
+    """What the engine wrote back to the store inside the window: its
+    planner decides, per fresh context."""
+    w = [b for t, b in window.written_back if window.start <= t <= window.end]
+    return {"written_back": len(w), "written_back_bytes": sum(w)}
+
+
 def reused_share(window: harness.Window) -> Optional[float]:
     """Store (``kvcache/hierarchy.py`` lookup and fetch): the share, in
     percent, of the context tokens of the requests admitted inside the
@@ -177,7 +185,8 @@ def run_cell(
     metrics: List[dict], trace_dir: Optional[Path] = None, t_start: Optional[float] = None,
     notes: Optional[dict] = None,
     configs_dir: Path = harness.CONFIGS, traffic_dir: Path = traffic_mod.TRAFFIC_DIR,
-    limits_dir: Path = correct.LIMITS_DIR, engine_hook: Optional[Callable] = None,
+    limits_dir: Path = correct.LIMITS_DIR, models_dir: Path = harness.MODELS,
+    engine_hook: Optional[Callable] = None,
 ):
     """One run: returns (result, checked).  ``engine_hook(engine)`` may
     replace parts of the engine before the fill (the fault tests)."""
@@ -189,7 +198,9 @@ def run_cell(
     spec = traffic_mod.load(w["traffic"], w["config"], traffic_dir)
     say("device", platform=device.platform, device_kind=device.device_kind,
         count=n_devices, **(notes or {}))
-    engine, dims = harness.build(conf, seed, device)
+    engine, dims = harness.build(conf, seed, device, models_dir=models_dir)
+    model = harness.model_of(conf, models_dir)
+    progs = harness.programs(engine)
     if engine_hook is not None:
         engine_hook(engine)
     traffic = traffic_mod.generate(spec, seed, seconds, dims.vocab)
@@ -239,7 +250,7 @@ def run_cell(
     say("requests", **counts)
     say("backlog", **backlog(window))
     say("latency", **latency(window))
-    say("store", reused_context_share=reused_share(window))
+    say("store", reused_context_share=reused_share(window), **written_back(window))
     stats_mem = device.memory_stats() or {}
     peak_mem = stats_mem.get("peak_bytes_in_use")
     say("memory", peak_bytes_in_use=peak_mem, bytes_limit=stats_mem.get("bytes_limit"))
@@ -250,8 +261,8 @@ def run_cell(
     t0 = time.perf_counter()
     from bench import weights as bench_weights
 
-    wts = bench_weights.make(dims, seed, device)
-    read = correct.readings(wts, dims, correct.sample(finished, seed))
+    wts = bench_weights.make(model, dims, seed, device)
+    read = correct.readings(model, wts, dims, correct.sample(finished, seed))
     del wts
     harness.free_device()
     say("check", seconds=time.perf_counter() - t0, **read)
@@ -267,7 +278,8 @@ def run_cell(
     peak = None
     if device.platform == "tpu":
         peak = peaks(device.device_kind)
-    view = RunView(window=window, dims=dims, peak=peak, setup_s=setup_s, trace=reduction)
+    view = RunView(window=window, dims=dims, peak=peak, setup_s=setup_s, trace=reduction,
+                   model=model, programs=progs)
     if reduction is not None and peak is not None:
         say("roofline_bounds", **roofline_bounds(view))
     out = {}

@@ -41,12 +41,12 @@ def main(argv=None) -> int:
     from bench import runner
     from bench import traffic as traffic_mod
     from bench import weights as bench_weights
-    from bench.flops import Dims
 
     conf = harness.load_config(w["config"])
-    dims = Dims.from_config(conf["config"])
+    model = harness.model_of(conf)
+    dims = model.sizes(conf["config"])
     spec = traffic_mod.load(w["traffic"], w["config"])
-    wts = bench_weights.make(dims, args.seed, devs[0])
+    wts = bench_weights.make(model, dims, args.seed, devs[0])
     e2e = run.metric_specs(bench, args.workload, False)
     for rate in [float(r) for r in args.rates.split(",")]:
         t0 = time.perf_counter()
@@ -56,11 +56,13 @@ def main(argv=None) -> int:
         warmed = harness.warm(engine, tr, matched)
         setup = time.perf_counter() - t0
         window = harness.serve(engine, tr, args.seconds, drain_s=DRAIN_S)
-        view = runner.RunView(window=window, dims=dims, peak=None, setup_s=setup)
+        view = runner.RunView(window=window, dims=dims, peak=None, setup_s=setup, model=model)
         metrics = {m["name"]: runner.reader(m["name"])(view) for m in e2e}
         print(json.dumps({"line": "sweep", "rate_per_s": rate, "metrics": metrics,
                           **runner.phase_counts(window), **runner.backlog(window),
                           "latency": runner.latency(window),
+                          "reused_context_share": runner.reused_share(window),
+                          **runner.written_back(window),
                           "warmed": warmed}), flush=True)
         del engine, window
         harness.free_device(keep=wts)

@@ -26,6 +26,11 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[int, int]  # (start_ns, end_ns)
 
+# the profiler aligns the device plane to the host's only to about a
+# millisecond: a program can show up to that much before the host call that
+# launched it, or after the host saw it end
+SKEW_NS = 1_000_000
+
 _OP_NAME = re.compile(r"^%?([A-Za-z_][\w\-]*?)(?:\.\d+)?(?: =|$)")
 
 
@@ -110,9 +115,11 @@ class Reduction:
 
     def executions(self, prefix: str) -> List[Event]:
         """Executions of the program whose module name starts with ``prefix``,
-        inside the window."""
+        inside the window (to within ``SKEW_NS`` at its edges: the steps at
+        the window's ends launch and sync their programs inside it)."""
         return [m for m in self.modules
-                if m.name.startswith(prefix) and self.lo <= m.start and m.end <= self.hi]
+                if m.name.startswith(prefix) and self.lo - SKEW_NS <= m.start
+                and m.end <= self.hi + SKEW_NS]
 
     def kernel_time(self, run: Event, kernel: str) -> int:
         """ns the ops named ``kernel`` ran inside one program execution."""

@@ -18,7 +18,9 @@ Before the window, a lead-in of ``arrival.lead_s`` seconds runs the same
 Poisson stream on a fixed subset of the same requests (the seed draws
 only its order), so that the window opens on an engine already under
 load; the lead-in's requests fall due at negative times and are not
-attempted ones.
+attempted ones.  Where each document is asked once
+(``asks_per_document`` 1), the lead-in's requests get documents of their
+own, of the same lengths, so that every document of the run is asked once.
 """
 from __future__ import annotations
 
@@ -89,7 +91,7 @@ class Req:
 
 @dataclasses.dataclass
 class Traffic:
-    docs: List[List[int]]  # the document pool (token ids)
+    docs: List[List[int]]  # the document pool (token ids), the lead-in's own last
     requests: List[Req]  # in arrival order, the lead-in's first
     fill_prompts: List[List[int]]  # one per document, for the set-up fill
     expected_reuses: int
@@ -144,6 +146,12 @@ def generate(
         ))
     fill_len = int(spec["fill_prompt_tokens"])
     fills = [rng.integers(0, vocab, fill_len).tolist() for _ in docs]
+    if int(spec["documents"]["asks_per_document"]) == 1:
+        # every document asked once: the lead-in's copies get documents of
+        # their own, of the same lengths, so that none is seen twice
+        for r in out[:n_lead]:
+            docs.append(rng.integers(0, vocab, len(docs[r.doc])).tolist())
+            r.doc = len(docs) - 1
     return Traffic(
         docs=docs, requests=out, fill_prompts=fills,
         expected_reuses=int(spec["documents"]["asks_per_document"]),

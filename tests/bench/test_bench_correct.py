@@ -10,22 +10,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench import correct, harness, reference, runner
+from bench import correct, harness, runner
 from bench import traffic as T
 from bench import weights as W
-from bench.flops import Dims
 
 DATA = harness.REPO / "tests" / "bench" / "data"
 CELL = {"name": "tiny.docqa", "config": "tiny", "traffic": "docqa_tiny", "chips": 1}
+# every document seen once: the fill stores nothing, every request is fresh
+FRESH = {"name": "tiny.docqa_fresh", "config": "tiny", "traffic": "docqa_tiny_fresh", "chips": 1}
 BENCH = json.loads((harness.REPO / "BENCHMARK.json").read_text())
 CONF = harness.load_config("tiny", DATA / "configs")
-DIMS = Dims.from_config(CONF["config"])
+MODEL = harness.model_of(CONF)
+DIMS = MODEL.sizes(CONF["config"])
 LIMIT = correct.limits(CELL["name"], DATA / "limits")["max_logit_gap"]
 
 
-def _run(seed, hook=None):
-    metrics = [dict(m, workloads=[CELL["name"]]) for m in BENCH["end_to_end"]]
-    return runner.run_cell(BENCH, CELL, seed=seed, seconds=2.0, device=jax.devices()[0],
+def _run(seed, hook=None, cell=CELL):
+    metrics = [dict(m, workloads=[cell["name"]]) for m in BENCH["end_to_end"]]
+    return runner.run_cell(BENCH, cell, seed=seed, seconds=2.0, device=jax.devices()[0],
                            n_devices=1, metrics=metrics, configs_dir=DATA / "configs",
                            traffic_dir=DATA / "traffic", limits_dir=DATA / "limits",
                            engine_hook=hook)
@@ -63,6 +65,26 @@ def test_altered_token_is_not_correct():
     assert checked["max_logit_gap"]["value"] > LIMIT
 
 
+def test_fresh_documents_bypass_the_store_and_compile_nothing_in_the_window(capsys):
+    """Every document asked once: the fill stores none, every admission is
+    fresh (matched 0) and its shape was warmed, and the run is correct."""
+    result, checked = _run(2**33 + 7, cell=FRESH)
+    assert result["correct"] is True and result["failed"] == 0
+    lines = {d["line"]: d for d in map(json.loads, capsys.readouterr().out.splitlines())
+             if "line" in d}
+    assert lines["setup"]["fill_s"] < 0.01
+    for phase in ("compilations_in_window", "compilations_in_lead_in"):
+        assert (lines[phase]["compiles"], lines[phase]["cache_loads"]) == (0, 0)
+    assert lines["store"]["reused_context_share"] == 0.0
+    assert lines["store"]["written_back"] > 0  # the tiny configuration's planner always stores
+
+
+def test_fresh_altered_token_is_not_correct():
+    result, checked = _run(2**33 + 7, hook=_second_best, cell=FRESH)
+    assert result["correct"] is False
+    assert checked["max_logit_gap"]["value"] > LIMIT
+
+
 @pytest.fixture(scope="module")
 def served():
     engine, _ = harness.build(CONF, 11, jax.devices()[0])
@@ -74,7 +96,7 @@ def served():
 
 
 def test_float8_control_fails_the_limit(served):
-    read = correct.readings(W.make(DIMS, 11), DIMS, served, control=True)
+    read = correct.readings(MODEL, W.make(MODEL, DIMS, 11), DIMS, served, control=True)
     assert read["served_tokens"] >= 50
     assert read["max_logit_gap"] <= LIMIT
     assert read["control_max_logit_gap"] > LIMIT
@@ -86,6 +108,18 @@ def test_runner_up_gap_is_the_top_two_margin():
     ref = np.array([[0.0, 3.0, 2.5, -1.0], [4.0, 1.0, 0.0, 3.9]])
     np.testing.assert_allclose(correct.runner_up_gaps(ref), [0.5, 0.1])
     np.testing.assert_allclose(correct.served_gaps(ref, [2, 3]), [0.5, 0.1])
+
+
+def test_fill_stores_only_documents_asked_more_than_once():
+    engine, dims = harness.build(CONF, 13, jax.devices()[0])
+    tr = T.generate(T.load("docqa_tiny_fresh", "tiny", DATA / "traffic"), 13, 2.0, dims.vocab)
+    assert harness.fill(engine, tr) == [0] * len(tr.docs)
+    assert not engine.store.entries
+    assert harness.programs(engine) == {
+        "admit": harness.Program("jit__packed_prefill_impl", per_step=True),
+        "decode": harness.Program("jit__decode_impl", per_step=True)}
+    sets = harness.admission_sets(engine, tr, [0] * len(tr.docs))
+    assert sets and all(m == 0 for run in sets for m, _ in run)
 
 
 def test_an_engine_without_its_admission_calls_is_not_warmed():
@@ -125,10 +159,10 @@ def test_reference_matches_the_program_forward_in_float32():
 
     cfg = harness.program_config(CONF)
     cfg32 = dataclasses.replace(cfg, dtype="float32", param_dtype="float32")
-    wts = W.make(DIMS, 4)
+    wts = W.make(MODEL, DIMS, 4)
     tokens = np.random.default_rng(0).integers(0, DIMS.vocab, 300).tolist()
     rows = list(range(0, 300, 37))
-    ours = reference.logits(wts, DIMS, tokens, rows)
+    ours = MODEL.logits(wts, DIMS, tokens, rows)
     ops.set_kernel_mode("ref")
     try:
         with jax.default_matmul_precision("highest"):

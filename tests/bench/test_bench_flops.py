@@ -6,7 +6,9 @@ import json
 import pytest
 
 from bench import flops, harness
-from bench.flops import Dims
+
+M = harness.model("dense_gqa")
+Dims = M.Dims
 
 CONFIGS = ["qwen2-1.5b", "mistral-nemo-12b-8l"]
 
@@ -15,7 +17,7 @@ def _both(name):
     from repro.core.perf_model import PerfModel, tpu_v5e
 
     conf = harness.load_config(name)
-    return Dims.from_config(conf["config"]), harness.program_config(conf), PerfModel(tpu_v5e(1))
+    return M.sizes(conf["config"]), harness.program_config(conf), PerfModel(tpu_v5e(1))
 
 
 def _non_matmul_params(d: Dims) -> int:
@@ -34,7 +36,7 @@ def _non_matmul_params(d: Dims) -> int:
 @pytest.mark.parametrize("n", [1, 128, 4096, 16896])
 def test_prefill_flops_match_cost_model(name, n):
     d, cfg, pm = _both(name)
-    ours = flops.prefill_flops_all_logits(d, n) + 2.0 * _non_matmul_params(d) * n
+    ours = M.prefill_flops_all_logits(d, n) + 2.0 * _non_matmul_params(d) * n
     assert ours == pytest.approx(pm.prefill_flops(cfg, n), rel=1e-12)
 
 
@@ -43,30 +45,30 @@ def test_prefill_flops_match_cost_model(name, n):
 def test_decode_bytes_match_cost_model(name, ctx):
     d, cfg, pm = _both(name)
     untied_table = 0 if d.tied else flops.BYTES * d.vocab * d.d_model
-    assert flops.decode_bytes_per_token(d, ctx) + untied_table == pm.decode_bytes_per_token(cfg, ctx)
+    assert M.decode_bytes_per_token(d, ctx) + untied_table == pm.decode_bytes_per_token(cfg, ctx)
 
 
 def test_segment_flops_count_the_causal_pairs():
     d, _, _ = _both("qwen2-1.5b")
     # one token behind 10 reused positions attends 11; a 3-token prefill attends 1+2+3
     assert flops.causal_pairs(10, 1) == 11 and flops.causal_pairs(0, 3) == 6
-    full = flops.prefill_segment_flops(d, 0, 4096)
-    split = flops.prefill_segment_flops(d, 4000, 96)
-    layer = 2 * d.n_layers * flops.layer_matmul_params(d)
-    assert full - split == layer * 4000 + d.n_layers * flops.attn_pair_flops(d) * (
+    full = M.prefill_segment_flops(d, 0, 4096)
+    split = M.prefill_segment_flops(d, 4000, 96)
+    layer = 2 * d.n_layers * M.layer_matmul_params(d)
+    assert full - split == layer * 4000 + d.n_layers * M.attn_pair_flops(d) * (
         flops.causal_pairs(0, 4096) - flops.causal_pairs(4000, 96))
     # a decoded token is a one-token prefill behind the rest
-    assert flops.decode_token_flops(d, 501) == flops.prefill_segment_flops(d, 500, 1)
+    assert M.decode_token_flops(d, 501) == M.prefill_segment_flops(d, 500, 1)
 
 
 def test_kernel_calls_and_roofline():
     d, _, _ = _both("qwen2-1.5b")
     peak = flops.peaks("TPU v5 lite")
-    f, b = flops.decode_attention_call(d, [1000, 2000])
-    assert f == flops.attn_pair_flops(d) * 3000
+    f, b = M.decode_attention_call(d, [1000, 2000])
+    assert f == M.attn_pair_flops(d) * 3000
     assert b == 2 * (2 * 3000 * d.n_kv_heads * d.head_dim + 2 * 2 * d.n_heads * d.head_dim)
     assert flops.least_time(f, b, peak)[1] == "memory"
-    f, b = flops.packed_prefill_call(d, [(0, 8192)])
+    f, b = M.packed_prefill_call(d, [(0, 8192)])
     assert flops.least_time(f, b, peak)[1] == "compute"
 
 

@@ -7,11 +7,11 @@ import json
 
 import pytest
 
-from bench import harness, host_spans, match, runner, trace
+from bench import harness, host_spans, runner, trace
 from bench.host_spans import HostEvent
 from repro.obs.spans import Span
 
-from test_bench_trace import TRACE, view  # noqa: F401  (fixture)
+from test_bench_trace import PROGRAMS, TRACE, view  # noqa: F401  (fixture)
 
 SPANS = TRACE.parent / "trace_spans"
 
@@ -170,7 +170,7 @@ def test_each_packed_launch_precedes_its_execution(spans_run):
                 if h.name == "engine.launch" and h.attrs.get("program") == "packed_prefill"]
     assembles = [h for h in host_spans.in_window(events, red.lo, red.hi)
                  if h.name == "engine.assemble"]
-    runs = red.executions(match.PACKED_PROGRAM)
+    runs = red.executions(PROGRAMS["admit"].name)
     assert len(launches) == len(runs) == len(assembles) > 0
     for k, (launch, asm, ex) in enumerate(zip(launches, assembles, runs)):
         assert asm.start < ex.start and ex.start > launch.start - 1_000_000
@@ -181,8 +181,8 @@ def test_each_packed_launch_precedes_its_execution(spans_run):
 def test_decode_host_time_and_the_idle_split_on_the_chip_trace(spans_run, tmp_path,
                                                                monkeypatch):
     red, events = spans_run
-    split = host_spans.decode_split(events, red)
-    assert split["steps"] == len(red.executions(match.DECODE_PROGRAM)) > 0
+    split = host_spans.decode_split(events, red, PROGRAMS["decode"].name)
+    assert split["steps"] == len(red.executions(PROGRAMS["decode"].name)) > 0
     # a decode step is its host time beside the device plus the program
     assert split["host_ms"] + split["program_ms"] == pytest.approx(split["step_ms"], rel=0.03)
     idle = host_spans.idle_by_span(events, red)

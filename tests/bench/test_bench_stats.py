@@ -3,9 +3,8 @@ failed requests as missing the limit, the window, tokens per second."""
 import pytest
 
 from bench import harness, runner, stats
-from bench.flops import Dims
 
-DIMS = Dims(d_model=8, n_layers=1, n_heads=2, n_kv_heads=1, head_dim=4, d_ff=16,
+DIMS = harness.model("dense_gqa").Dims(d_model=8, n_layers=1, n_heads=2, n_kv_heads=1, head_dim=4, d_ff=16,
             vocab=32, tied=True, qkv_bias=False, rope_theta=1e4, norm_eps=1e-5)
 
 

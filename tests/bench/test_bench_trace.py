@@ -8,10 +8,12 @@ import json
 import pytest
 
 from bench import flops, harness, match, runner, trace
-from bench.flops import Dims
 
 DATA = harness.REPO / "tests" / "bench" / "data"
 TRACE = DATA / "trace"
+# the programs of an engine that packs its admissions (harness.programs)
+PROGRAMS = {"admit": harness.Program("jit__packed_prefill_impl", per_step=True),
+            "decode": harness.Program("jit__decode_impl", per_step=True)}
 
 
 def test_op_names():
@@ -49,8 +51,10 @@ def view():
     red = trace.reduce_file(str(TRACE / "trace.xplane.pb.gz"), [s.kind for s in window.steps
                                                               if s.in_window])
     conf = harness.load_config("small", DATA / "configs")
-    return runner.RunView(window=window, dims=Dims.from_config(conf["config"]),
-                          peak=flops.peaks("TPU v5 lite"), setup_s=1.0, trace=red)
+    model = harness.model_of(conf)
+    return runner.RunView(window=window, dims=model.sizes(conf["config"]),
+                          peak=flops.peaks("TPU v5 lite"), setup_s=1.0, trace=red,
+                          model=model, programs=PROGRAMS)
 
 
 def test_window_and_busy(view):
@@ -63,16 +67,17 @@ def test_window_and_busy(view):
 
 
 def test_programs_pair_with_steps(view):
-    for kind, program in (("admit", match.PACKED_PROGRAM), ("decode", match.DECODE_PROGRAM)):
-        got = match.pairs(view, kind, program)
+    for kind in ("admit", "decode"):
+        got = match.pairs(view, kind)
         assert got, kind
         for step, ex in got:
-            assert ex.end - ex.start > 0
+            assert len(ex) == 1 and ex[0].end - ex[0].start > 0
 
 
 def test_a_program_the_trace_does_not_show_fails(view):
     with pytest.raises(match.Unpaired):
-        match.pairs(view, "admit", "jit__renamed_prefill")
+        renamed = dict(PROGRAMS, admit=harness.Program("jit__renamed_prefill", per_step=True))
+        match.pairs(dataclasses.replace(view, programs=renamed), "admit")
     with pytest.raises(match.Unpaired):
         steps = list(view.window.steps)
         steps.remove(next(st for st in steps if st.in_window and st.kind == "admit"))
@@ -81,10 +86,10 @@ def test_a_program_the_trace_does_not_show_fails(view):
 
 
 def test_kernel_time_inside_its_program(view):
-    packed = match.pairs(view, "admit", match.PACKED_PROGRAM)
-    decode = match.pairs(view, "decode", match.DECODE_PROGRAM)
-    assert all(view.trace.kernel_time(ex, "packed_flash_attention") > 0 for _, ex in packed)
-    for _, ex in decode:
+    packed = match.pairs(view, "admit")
+    decode = match.pairs(view, "decode")
+    assert all(view.trace.kernel_time(ex, "packed_flash_attention") > 0 for _, (ex,) in packed)
+    for _, (ex,) in decode:
         t = view.trace.kernel_time(ex, "decode_attention")
         assert 0 < t <= ex.end - ex.start
 

@@ -2,6 +2,7 @@
 seed in another order, the lead-in, and the stated distributions."""
 import json
 import math
+from collections import Counter
 from statistics import NormalDist
 
 import numpy as np
@@ -121,3 +122,19 @@ def test_longest_request_fits_max_len():
         need = sum(SPEC[k]["length"]["max"] for k in ("documents", "question", "output")) + 32
         assert conf["engine"]["overrides"]["max_len"] == -(-need // 128) * 128
 
+
+
+def test_documents_asked_once_are_each_their_own():
+    """``asks_per_document`` 1: one document per request, the lead-in's too,
+    with the lead-in's documents as long as those of the requests it copies."""
+    spec = T.load("docqa_fresh", "qwen2-1.5b")
+    tr = T.generate(spec, 2**33 + 5, 51, VOCAB)
+    assert sorted(r.doc for r in tr.requests) == list(range(len(tr.docs)))
+    assert tr.expected_reuses == 1
+    lead = [r for r in tr.requests if r.due_s < 0]
+    win = [r for r in tr.requests if r.due_s >= 0]
+    assert lead and len(win) == len(tr.fill_prompts)
+    shape = lambda r: (len(tr.docs[r.doc]), len(r.question), r.max_new_tokens)  # noqa: E731
+    assert not Counter(map(shape, lead)) - Counter(map(shape, win))
+    other = T.generate(spec, 7, 51, VOCAB)
+    assert sorted(map(len, other.docs)) == sorted(map(len, tr.docs))
